@@ -35,8 +35,8 @@ def main():
         for vb, prec in ((4, "f32"), (8, "f64")):
             ehyb = e.bytes_moved(vb)["total"]            # paper's sliced-ELL
             ehyb32 = e.bytes_moved(vb, col_bytes=4)["total"]  # int32 ablation
-            etile = e.bytes_moved(vb, layout="tile")["total"]  # kernel v1
-            epack = e.bytes_moved(vb, layout="packed")["total"]  # kernel v2
+            etile = e.bytes_moved(vb, layout="tile")["total"]  # XLA tiles
+            epack = e.bytes_moved(vb, layout="packed")["total"]  # Pallas
             ebuck = b.bytes_moved(vb)["total"]
             lo = csr_bytes(m, vb, True)
             hi = csr_bytes(m, vb, False)

@@ -16,19 +16,16 @@ For every suite matrix × mesh size this module records, per SpMV iteration:
 * distributed vs local ``solve()`` wall time and residuals (the
   correctness contract: same tolerance, same trajectory).
 
-Multi-device execution needs host platform devices, so the measurement runs
-in a child process with ``XLA_FLAGS=--xla_force_host_platform_device_count``
-set (the same pattern as tests/test_sharding.py); ``main()`` orchestrates
-and returns the records that ``benchmarks/run.py`` commits to
-``BENCH_spmv.json``.
+Everything runs in this process on ``jax.devices()``: one process per
+chip, so the device is never held by a parent while a child needs it.  On
+the CPU the devices are virtual, from the caller's
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (as the CI
+``multi-device`` job sets it); mesh sizes larger than the device count are
+skipped.  ``main()`` returns the records that ``benchmarks/run.py`` commits
+to ``BENCH_spmv.json``.
 """
 
 from __future__ import annotations
-
-import json
-import os
-import subprocess
-import sys
 
 DEFAULT_MATRICES = ("poisson3d_16", "poisson3d_24", "poisson27_12",
                     "elasticity_8", "unstruct_4k", "powerlaw_4k",
@@ -38,7 +35,7 @@ DEFAULT_NDEV = (4, 8)
 QUICK_NDEV = (4,)
 
 
-def _child(matrices, n_devs, max_iters: int) -> list:
+def _measure(matrices, n_devs, max_iters: int) -> list:
     import time
 
     import jax
@@ -120,22 +117,17 @@ def _child(matrices, n_devs, max_iters: int) -> list:
 
 
 def main(quick: bool = False) -> list:
+    import jax
+
     matrices = QUICK_MATRICES if quick else DEFAULT_MATRICES
-    n_devs = QUICK_NDEV if quick else DEFAULT_NDEV
-    max_iters = 40 if quick else 120
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                        f"{max(n_devs)}")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src"), root, env.get("PYTHONPATH", "")])
-    cmd = [sys.executable, "-m", "benchmarks.dist_halo", "--child",
-           ",".join(matrices), ",".join(map(str, n_devs)), str(max_iters)]
-    out = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                         cwd=root, timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(f"dist_halo child failed:\n{out.stderr[-3000:]}")
-    records = json.loads(out.stdout.strip().splitlines()[-1])
+    n_devs = tuple(d for d in (QUICK_NDEV if quick else DEFAULT_NDEV)
+                   if d <= len(jax.devices()))
+    if not n_devs:
+        raise RuntimeError(
+            f"dist_halo needs at least {min(QUICK_NDEV)} devices, found "
+            f"{len(jax.devices())}; on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=8")
+    records = _measure(matrices, n_devs, 40 if quick else 120)
     from .emit_util import emit_kv
 
     for r in records:
@@ -152,9 +144,4 @@ def main(quick: bool = False) -> list:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "--child":
-        mats = sys.argv[2].split(",")
-        ndevs = tuple(int(x) for x in sys.argv[3].split(","))
-        print(json.dumps(_child(mats, ndevs, int(sys.argv[4]))))
-    else:
-        main()
+    main()

@@ -295,6 +295,9 @@ def main(argv=None) -> None:
                          "BENCH_spmv.json (persists to REPRO_TUNE_CACHE "
                          "when set)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.calibrate and not args.modules:
         # calibration is its own measured pass — don't drag the full

@@ -35,7 +35,7 @@ def main():
 
     rows32 = spmv_throughput.run("f32")
     t1 = summarize(rows32, "f32")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         rows64 = spmv_throughput.run("f64")
     t2 = summarize(rows64, "f64")
     # rows_f32 rides along so run.py's BENCH_spmv.json stage can reuse the

@@ -41,7 +41,7 @@ def run(dtype_name: str = "f32", suite=None):
 
 def main():
     rows32 = run("f32")
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         rows64 = run("f64")
     return {"f32": rows32, "f64": rows64}
 

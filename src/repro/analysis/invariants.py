@@ -315,6 +315,12 @@ def _check_fill_plan(e, plan, widths) -> List[Finding]:
     return out
 
 
+def _tile_slots(packed_vals) -> int:
+    """Rows per packed tile: (P, T, Sb, 128) tables hold Sb·128 each."""
+    shape = np.shape(packed_vals)
+    return int(shape[2] * shape[3])
+
+
 def check_packed_host(pk) -> List[Finding]:
     """Invariants of a host ``PackedEHYB`` staircase packing (+ its base)."""
     site = "PackedEHYB"
@@ -333,14 +339,16 @@ def check_packed_host(pk) -> List[Finding]:
     if cr.size and (int(cr.min()) < 0 or int(cr.max()) > V):
         out.append(_f("error", f"{site}.col_rows", "width-consistency",
                       f"col_rows escape [0, {V}]"))
+    tile = _tile_slots(pk.packed_vals)
     if not (np.array_equal(cs[:, 0], np.zeros(P, dtype=cs.dtype))
-            and np.array_equal(np.diff(cs, axis=1), cr)):
+            and np.array_equal(np.diff(cs, axis=1), -(-cr // tile))):
         out.append(_f("error", f"{site}.col_starts", "width-consistency",
-                      "col_starts is not the running sum of col_rows"))
-    elif int(cs[:, -1].max(initial=0)) > pk.packed_len:
+                      "col_starts is not the running sum of the column "
+                      "tile counts"))
+    elif int(cs[:, -1].max(initial=0)) * tile > pk.packed_len:
         out.append(_f("error", f"{site}.col_starts", "width-consistency",
-                      f"packed stream length {int(cs[:, -1].max())} exceeds "
-                      f"packed_len {pk.packed_len}"))
+                      f"packed stream of {int(cs[:, -1].max())} tiles "
+                      f"exceeds packed_len {pk.packed_len}"))
     if pk.pack_plan is not None:
         pp = pk.pack_plan
         key = np.asarray(pp["pi"], np.int64) * pk.packed_len + \
@@ -450,10 +458,12 @@ def check_packed_device(d) -> List[Finding]:
     if cr.size and (int(cr.min()) < 0 or int(cr.max()) > d.vec_size):
         out.append(_f("error", f"{site}.col_rows", "width-consistency",
                       f"col_rows escape [0, {d.vec_size}]"))
+    tile = _tile_slots(d.packed_vals)
     if not (np.array_equal(cs[:, 0], np.zeros(cs.shape[0], dtype=cs.dtype))
-            and np.array_equal(np.diff(cs, axis=1), cr)):
+            and np.array_equal(np.diff(cs, axis=1), -(-cr // tile))):
         out.append(_f("error", f"{site}.col_starts", "width-consistency",
-                      "col_starts is not the running sum of col_rows"))
+                      "col_starts is not the running sum of the column "
+                      "tile counts"))
     elif cs.size and int(cs[:, -1].max()) > np.asarray(
             d.packed_vals).shape[1]:
         out.append(_f("error", f"{site}.col_starts", "width-consistency",

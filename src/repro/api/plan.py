@@ -584,9 +584,9 @@ class Plan:
 
     def _raw_apply(self, tpl=None):
         """The format's original-space ``(obj, x) -> y`` closure, wrapped in
-        the reliability guard: a Pallas lowering/compile failure downgrades
-        through the fallback chain (fused -> unfused -> reference) at host
-        dispatch instead of crashing the apply.  Sharded plans dispatch
+        the reliability guard: off the TPU (or under fault injection) a
+        Pallas lowering/compile failure downgrades to the reference apply
+        at host dispatch instead of crashing; on the TPU it raises.  Sharded plans dispatch
         inside shard_map and keep the unguarded closure."""
         tpl = tpl or self._any_template()
         if self.is_sharded:

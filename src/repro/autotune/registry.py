@@ -10,9 +10,9 @@ format as a candidate:
   sparsity pattern alone — no device arrays are allocated for losers.
   ``context`` distinguishes one-shot original-space calls ("spmv") from
   permuted-space solver iterations ("solver") — see ``cost.py``;
-* ``kernel`` — which execution engine backs it ("xla" or
-  "pallas-interpret"); the tuner's measured pass skips interpreter-backed
-  kernels on CPU where their timings are meaningless;
+* ``kernel`` — which execution engine backs it ("xla" or "pallas").  A
+  Pallas kernel is compiled on the TPU and interpreted on CPU, so the
+  tuner never selects or times it on CPU, where its timings say nothing;
 * ``permuted`` — optional ``apply_permuted(obj, x_new)`` running the SpMV in
   the format's reordered padded space (EHYB family), the hook behind
   ``SpMVOperator.matvec_permuted`` and the permuted-space solver loop;
@@ -60,18 +60,11 @@ class FormatSpec:
     name: str
     build: Callable[..., tuple]        # (m, dtype, shared) -> (obj, apply)
     model: Callable[..., int]          # (m, stats, vb, shared, context, k)->B
-    kernel: str = "xla"                # "xla" | "pallas-interpret"
+    kernel: str = "xla"                # "xla" | "pallas"
     description: str = ""
     permuted: Optional[Callable] = None   # (obj, x_new) -> y_new, or None
     refill: Optional[Callable] = None     # (obj, m_new, dtype, shared) -> obj
     shard: Optional[Callable] = None      # (op, mesh, axis, csr) -> Sharded
-    # degraded apply levels for the guarded fallback chain
-    # (reliability.guard): same (obj, x)/(obj, x_new) signatures as
-    # apply/permuted but with the most specialized kernel stage dropped —
-    # e.g. ehyb_packed's packed-ELL kernel + jnp ER instead of the fused
-    # megakernel.  None = the chain goes native -> reference directly.
-    fallback: Optional[Callable] = None
-    fallback_permuted: Optional[Callable] = None
     # per-term byte breakdown along cost.TERMS (same accounting as ``model``,
     # split by traffic kind) — the calibration layer's feature vector.  None
     # = the whole model collapses into the sequential-stream term.
@@ -231,18 +224,6 @@ def _packed_permuted(d, x_new):
     from ..kernels.ops import ehyb_spmv_packed_pallas_permuted
 
     return ehyb_spmv_packed_pallas_permuted(d, x_new)
-
-
-def _packed_unfused(d, x):
-    from ..kernels.ops import ehyb_spmv_packed_pallas
-
-    return ehyb_spmv_packed_pallas(d, x, use_er_kernel=False)
-
-
-def _packed_unfused_permuted(d, x_new):
-    from ..kernels.ops import ehyb_spmv_packed_pallas_permuted
-
-    return ehyb_spmv_packed_pallas_permuted(d, x_new, use_er_kernel=False)
 
 
 def _build_dense(m, dtype, shared):
@@ -557,11 +538,10 @@ register_format(FormatSpec(
 register_format(FormatSpec(
     "ehyb_packed", _build_ehyb_packed, _model_ehyb_packed,
     terms=_terms_ehyb_packed,
-    kernel="pallas-interpret",
-    description="EHYB packed staircase (fused Pallas megakernel v2)",
+    kernel="pallas",
+    description="EHYB packed staircase (Pallas kernel, VMEM x-slice cache)",
     permuted=_packed_permuted, refill=_refill_ehyb_packed,
     shard=_shard_ehyb,
-    fallback=_packed_unfused, fallback_permuted=_packed_unfused_permuted,
     invariants=_invariants_hook("ehyb_packed")))
 register_format(FormatSpec(
     "dense", _build_dense, _model_dense, terms=_terms_dense,

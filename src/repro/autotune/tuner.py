@@ -6,8 +6,9 @@
    (``cost.rank_formats``; one shared EHYB host build serves the family);
 2. **measured pass** (``mode="measure"``) — build the ``top_k`` model-ranked
    candidates and time their jitted SpMV on the current backend, picking the
-   fastest.  Interpreter-backed kernels are skipped on CPU where their
-   timings say nothing about device performance;
+   fastest.  Pallas kernels are skipped on CPU (interpreted there, their
+   timings say nothing about device performance); on the TPU a Pallas
+   candidate that fails to compile raises instead of being skipped;
 3. **cache** — the decision is memoized under (pattern hash, dtype, mode,
    candidate set): re-tuning the same sparsity pattern is a dict lookup, and
    a fixed pattern hash always yields the same selection (pinned by
@@ -243,10 +244,11 @@ def autotune(m: SparseCSR, dtype=None, *, mode: str = "model",
             for f in timed:
                 # a candidate whose build/compile/run fails (organically or
                 # chaos-injected) is skipped, not fatal — the measured pass
-                # ranks whatever actually executes on this backend
+                # ranks whatever actually executes on this backend; only a
+                # Pallas candidate on the TPU (no chaos armed) raises
+                spec = get_format(f)
                 try:
                     _chaos_check(f"tune:{f}")
-                    spec = get_format(f)
                     obj, apply = spec.build(m, dtype, shared)
                     if context == "solver" and spec.permuted is not None:
                         # time what the solver loop actually runs: the
@@ -261,6 +263,9 @@ def autotune(m: SparseCSR, dtype=None, *, mode: str = "model",
                     else:
                         measured[f] = _time_spmv(apply, obj, x)
                 except Exception as e:    # noqa: BLE001 — any kernel error
+                    if (spec.kernel != "xla" and _chaos_active() is None
+                            and jax.default_backend() == "tpu"):
+                        raise     # the chip's Pallas path must not vanish
                     import warnings
 
                     from ..core.counters import bump

@@ -1,77 +1,28 @@
-"""Version-compat shims over the moving parts of the jax API.
+"""Mesh, shard_map and tracer helpers over the installed jax (0.9).
 
-The repo targets the jax the container ships (0.4.x today) while staying
-forward-compatible with the 0.5+/0.6+ API renames:
-
-* ``jax.sharding.AxisType`` (new) vs no axis types at all (old) — meshes are
-  built through :func:`make_mesh`, which passes ``axis_types`` only when the
-  running jax understands it.
-* ``jax.shard_map(..., check_vma=...)`` (new) vs
-  ``jax.experimental.shard_map.shard_map(..., check_rep=...)`` (old) — use
-  :func:`shard_map`, which maps the replication-check flag to whichever
-  keyword exists.
-* tracing internals (``jax.core.Tracer`` / ``trace_state_clean``) have been
-  migrating out of ``jax.core`` — :func:`is_tracer` and
-  :func:`trace_state_clean` resolve whichever home the running jax uses, so
-  ``repro.api``'s dispatch never binds a moving attribute at import time.
-
-Keeping every call site on these helpers is what the sharding tests pin.
+Every call site builds meshes and shard_maps through these helpers, so an
+API move in jax is fixed in one place; the sharding tests pin that.
 """
 
 from __future__ import annotations
 
 import jax
+from jax._src.core import trace_state_clean  # noqa: F401  (re-export)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types when the API supports them."""
-    axis_type = getattr(getattr(jax, "sharding", None), "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_rep: bool = False):
-    """Dispatch to ``jax.shard_map`` (new) or experimental shard_map (old)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep)
-
-
-def _resolve(*candidates):
-    for modname, attr in candidates:
-        try:
-            mod = __import__(modname, fromlist=[attr])
-            fn = getattr(mod, attr, None)
-        except ImportError:
-            fn = None
-        if fn is not None:
-            return fn
-    return None
-
-
-_TRACER = _resolve(("jax.core", "Tracer"), ("jax._src.core", "Tracer"))
-_TRACE_STATE_CLEAN = _resolve(("jax.core", "trace_state_clean"),
-                              ("jax._src.core", "trace_state_clean"))
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-manual-axes check off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def is_tracer(x) -> bool:
-    """True when ``x`` is a jax tracer (any jax version's home for Tracer)."""
-    return _TRACER is not None and isinstance(x, _TRACER)
-
-
-def trace_state_clean() -> bool:
-    """True when no jax trace is active (conservatively False if the running
-    jax no longer exposes the probe — callers fall back to their trace-safe
-    path)."""
-    if _TRACE_STATE_CLEAN is None:
-        return False
-    return _TRACE_STATE_CLEAN()
+    """True when ``x`` is a jax tracer."""
+    return isinstance(x, jax.core.Tracer)

@@ -32,7 +32,7 @@ import numpy as np
 
 from .counters import bump
 from .matrices import SparseCSR
-from .partition import Partition, make_partition
+from .partition import LANES, Partition, lane_geometry, make_partition
 
 
 @dataclasses.dataclass
@@ -98,9 +98,10 @@ class EHYB:
 
         layout: "sliced"  — the paper's sliced-ELL (per 8-row-slice widths;
                             padding only inside a slice),
-                "tile"    — uniform (V, W) partition tiles (kernel v1),
-                "packed"  — per-partition packed slices padded to the max
-                            packed length across partitions (kernel v2).
+                "tile"    — uniform (V, W) partition tiles (the XLA path),
+                "packed"  — the Pallas kernel's staircase of aligned
+                            (Sb, 128) tiles, padded to the longest
+                            partition stream (what ``PackedEHYB`` stores).
 
         space: which vector space the caller hands x/y over in.
                "permuted" — the kernel-proper traffic (x and y already live in
@@ -144,8 +145,11 @@ class EHYB:
         elif layout == "sliced":
             ell_n = int(self.slice_widths.sum()) * 8
         else:  # packed
-            per_part = self.slice_widths.sum(axis=1) * 8
-            ell_n = int(per_part.max()) * self.n_parts
+            _, sb = lane_geometry(self.vec_size)
+            tile = sb * LANES
+            tiles = -(-staircase_rows(self) // tile)
+            ell_n = int(tiles.sum(axis=1).max(initial=1)) * tile \
+                * self.n_parts
         ell = ell_n * (val_bytes + col_bytes)
         x_cache = self.n_pad * val_bytes * k
         er_n = self.er_rows * self.er_width
@@ -401,18 +405,18 @@ def build_ehyb(m: SparseCSR, part: Optional[Partition] = None,
 
 
 # ---------------------------------------------------------------------------
-# ER-by-partition grouping (fused-megakernel metadata)
+# ER-by-partition grouping (per-partition ER tiles)
 # ---------------------------------------------------------------------------
 
 def group_er_by_partition(e: EHYB, sublane: int = 8) -> dict:
     """Map every ER slot to its owning partition (``er_row_idx // vec_size``).
 
-    The fused EHYB kernel runs one grid step per partition; giving step ``p``
-    its own ER rows lets it accumulate them into the same (V, R) output block
-    as the sliced-ELL part — no second pallas_call, no caller-side
-    scatter-add.  Returns uniform (P, E, We) tiles (E = max ER rows owned by
-    any partition, sublane-aligned; empty slots hold zero values and row 0,
-    which contribute nothing):
+    Every EHYB apply accumulates partition ``p``'s ER rows into the same
+    (V, R) output block as its sliced-ELL part — no caller-side global
+    scatter-add (``core.spmv._fused_er_parts``).  Returns uniform
+    (P, E, We) tiles (E = max ER rows owned by any partition,
+    sublane-aligned; empty slots hold zero values and row 0, which
+    contribute nothing):
 
       ``er_p_vals``  (P, E, We) float
       ``er_p_cols``  (P, E, We) int32 global-new column indices
@@ -463,26 +467,31 @@ def group_er_by_partition(e: EHYB, sublane: int = 8) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# packed "staircase" layout (kernel v2 — beyond-paper §Perf optimization)
+# packed "staircase" layout (the TPU kernel's storage)
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
 class PackedEHYB:
-    """Column-major staircase packing of the sliced-ELL part.
+    """Column-major staircase packing of the sliced-ELL part, in tiles.
 
     Within a partition, rows are width-sorted (paper Algo 1 l.17), so the
-    active cells of column k form a PREFIX of rows [0, R_k).  Storing columns
-    contiguously (vals/cols of column k at ``col_starts[p,k]``) eliminates
-    inter-slice padding: HBM bytes ≈ the paper's sliced-ELL accounting,
-    while the kernel keeps static-shape vector loads (dynamic offset, fixed
-    V-length, masked by R_k).
+    active cells of column k form a PREFIX of rows [0, R_k).  Column k is
+    stored as ceil(R_k / (Sb·128)) ``(Sb, 128)`` tiles starting at tile
+    ``col_starts[p, k]``; tile t holds rows [t·Sb·128, (t+1)·Sb·128) of the
+    column, lane-dense (row i at sublane (i % (Sb·128)) // 128, lane
+    i % 128).  Segments therefore start on tile boundaries, which is what
+    lets the TPU kernel load them at aligned dynamic offsets; the padding
+    that costs is at most one partial tile per column — the paper's
+    sliced-ELL with a slice of Sb·128 rows.  ``packed_len`` is the slots per
+    partition (tiles × Sb·128), the same for every partition so one block
+    shape covers the kernel.
     """
 
     base: EHYB
-    packed_len: int                   # L (max over partitions, + V guard)
-    packed_vals: np.ndarray           # (P, L) float
-    packed_cols: np.ndarray           # (P, L) uint16
-    col_starts: np.ndarray            # (P, W+1) int32 — column k offset
+    packed_len: int                   # slots per partition (T · Sb·128)
+    packed_vals: np.ndarray           # (P, T, Sb, 128) float
+    packed_cols: np.ndarray           # (P, T, Sb, 128) uint16
+    col_starts: np.ndarray            # (P, W+1) int32 — first tile of col k
     col_rows: np.ndarray              # (P, W) int32 — active rows R_k
     pack_plan: Optional[dict] = None  # (pi, vi, ki) -> (pi, dest) scatter
 
@@ -492,10 +501,11 @@ class PackedEHYB:
         if self.pack_plan is None:
             raise ValueError("this PackedEHYB carries no pack plan")
         p = self.pack_plan
-        packed_vals = np.zeros_like(self.packed_vals)
-        packed_vals[p["pi"], p["dest"]] = base.ell_vals[p["pi"], p["vi"],
-                                                        p["ki"]]
-        return dataclasses.replace(self, base=base, packed_vals=packed_vals)
+        flat = np.zeros((base.n_parts, self.packed_len),
+                        dtype=self.packed_vals.dtype)
+        flat[p["pi"], p["dest"]] = base.ell_vals[p["pi"], p["vi"], p["ki"]]
+        return dataclasses.replace(
+            self, base=base, packed_vals=flat.reshape(self.packed_vals.shape))
 
     def bytes_moved(self, val_bytes: int = 4, col_bytes: int = 2,
                     space: str = "permuted", fused_er: bool = True,
@@ -510,18 +520,9 @@ class PackedEHYB:
                 + b["interconnect"]}
 
 
-def pack_staircase(e: EHYB) -> PackedEHYB:
-    """Pack the (P, V, W) tiles column-major with no inter-slice padding.
-
-    Vectorized as one numpy scatter: cell (p, v, k) is active when
-    ``v < col_rows[p, k]`` (rows are width-sorted, so column k's active rows
-    are the prefix [0, R_k)), and its destination within partition p's packed
-    stream is ``col_starts[p, k] + v``.  The previous O(P·W) Python fill loop
-    dominated preprocessing on large matrices; the scatter is recorded in
-    ``preprocess_seconds["pack"]``.
-    """
-    bump("pack_staircase")
-    t0 = time.perf_counter()
+def staircase_rows(e: EHYB) -> np.ndarray:
+    """(P, W) int32 R_k: rows of each partition whose in-partition width
+    exceeds k — column k's active prefix (rows are width-sorted)."""
     p_, v_, w_ = e.n_parts, e.vec_size, e.ell_width
     if e.fill_plan is not None:
         # pattern widths (value-independent: explicit zeros stay packed, so
@@ -529,24 +530,44 @@ def pack_staircase(e: EHYB) -> PackedEHYB:
         widths = e.fill_plan["ell_widths"].reshape(p_, v_)
     else:
         widths = (e.ell_vals != 0).sum(axis=2)           # (P, V) row widths
-    # R_k per partition: number of rows with width > k (rows are sorted)
-    ks = np.arange(w_)[None, None, :]
-    col_rows = (widths[:, :, None] > ks).sum(axis=1).astype(np.int32)  # (P,W)
-    lens = col_rows.sum(axis=1)
-    pack_l = int(lens.max()) + v_                        # + V over-read guard
+    hist = np.bincount((np.arange(p_)[:, None] * (w_ + 1) + widths).ravel(),
+                       minlength=p_ * (w_ + 1)).reshape(p_, w_ + 1)
+    # R_k = #rows with width > k = reverse cumulative count from width k+1
+    return np.cumsum(hist[:, ::-1], axis=1)[:, ::-1][:, 1:].astype(np.int32)
+
+
+def pack_staircase(e: EHYB) -> PackedEHYB:
+    """Pack the (P, V, W) tiles column-major into aligned ``(Sb, 128)`` tiles.
+
+    Vectorized as one numpy scatter: cell (p, v, k) is active when
+    ``v < col_rows[p, k]`` (rows are width-sorted, so column k's active rows
+    are the prefix [0, R_k)), and its destination within partition p's
+    flat packed stream is ``col_starts[p, k]·(Sb·128) + v``.  The scatter
+    is recorded in ``preprocess_seconds["pack"]``.
+    """
+    bump("pack_staircase")
+    t0 = time.perf_counter()
+    p_, v_, w_ = e.n_parts, e.vec_size, e.ell_width
+    col_rows = staircase_rows(e)
+    _, sb = lane_geometry(v_)
+    tile = sb * LANES
+    col_starts = np.zeros((p_, w_ + 1), dtype=np.int32)
+    col_starts[:, 1:] = np.cumsum(-(-col_rows // tile), axis=1)
+    n_tiles = max(int(col_starts[:, -1].max()), 1)
+    pack_l = n_tiles * tile
     packed_vals = np.zeros((p_, pack_l), dtype=e.ell_vals.dtype)
     packed_cols = np.zeros((p_, pack_l), dtype=np.uint16)
-    col_starts = np.zeros((p_, w_ + 1), dtype=np.int32)
-    col_starts[:, 1:] = np.cumsum(col_rows, axis=1)
     active = np.arange(v_)[None, :, None] < col_rows[:, None, :]  # (P, V, W)
     pi, vi, ki = np.nonzero(active)
-    dest = col_starts[pi, ki] + vi
+    dest = col_starts[pi, ki].astype(np.int64) * tile + vi
     packed_vals[pi, dest] = e.ell_vals[pi, vi, ki]
     packed_cols[pi, dest] = e.ell_cols[pi, vi, ki]
     e.preprocess_seconds["pack"] = time.perf_counter() - t0
-    return PackedEHYB(base=e, packed_len=pack_l, packed_vals=packed_vals,
-                      packed_cols=packed_cols, col_starts=col_starts,
-                      col_rows=col_rows,
+    shape = (p_, n_tiles, sb, LANES)
+    return PackedEHYB(base=e, packed_len=pack_l,
+                      packed_vals=packed_vals.reshape(shape),
+                      packed_cols=packed_cols.reshape(shape),
+                      col_starts=col_starts, col_rows=col_rows,
                       pack_plan={"pi": pi, "dest": dest, "vi": vi, "ki": ki})
 
 

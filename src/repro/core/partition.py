@@ -90,6 +90,26 @@ class Partition:
 # cache sizing — the paper's Eq. 1–2 with TPU constants
 # ---------------------------------------------------------------------------
 
+LANES = 128
+SUBLANES = 8
+# Lane-rows of a partition's x-slice the TPU kernel's in-vreg gather may
+# span: every packed tile pays one gather+select pass per lane-row
+# (kernels/ehyb_spmv.py), so this caps vec_size at 16·128 = 2048.
+MAX_LANE_ROWS = 16
+
+
+def lane_geometry(vec_size: int) -> tuple:
+    """``(S, Sb)`` of the TPU kernel's lane-dense layout for a partition of
+    ``vec_size`` rows: S lane-rows of 128 hold the cached x-slice (a
+    multiple of 8 once it spans more than one vreg, and at least 2: Mosaic's
+    in-vreg gather refuses a one-row operand), and the packed staircase is
+    stored in ``(Sb, 128)`` tiles with Sb = min(S, 8)."""
+    s = max(2, -(-vec_size // LANES))
+    if s > SUBLANES:
+        s = -(-s // SUBLANES) * SUBLANES
+    return s, min(s, SUBLANES)
+
+
 def choose_vec_size(n: int, dtype_bytes: int = 4,
                     vmem_budget_bytes: int = 4 * 1024 * 1024,
                     p_units: int = 8, sublane: int = 8,
@@ -97,9 +117,12 @@ def choose_vec_size(n: int, dtype_bytes: int = 4,
     """Paper Eq. 1–2: smallest integer K with dim·τ/(K·P) < budget.
 
     GPU: budget = shared memory per SM, P = #SMs.  TPU: budget = the VMEM
-    slice we dedicate to the cached x block (default 4 MiB of ~128 MiB,
-    leaving room for value/col tiles and Mosaic double buffering), P = number
-    of concurrently-resident grid steps we aim for.
+    slice we dedicate to the cached x block, counted as the kernel lays it
+    out — lane-padded to S lane-rows of 128 (``lane_geometry``) — and P =
+    number of concurrently-resident grid steps we aim for.  On the TPU the
+    binding limit is the gather, not VMEM: the kernel gathers inside one
+    vreg and pays one pass per lane-row of the slice, so S is capped at
+    ``MAX_LANE_ROWS``.
 
     Returns (n_parts, vec_size); vec_size is sublane-aligned and < 2^16 so
     local column indices fit int16 (paper §3.4).
@@ -111,7 +134,9 @@ def choose_vec_size(n: int, dtype_bytes: int = 4,
         n_parts = k * p_units
         vec_size = -(-n // n_parts)                    # ceil
         vec_size = -(-vec_size // sublane) * sublane   # sublane align
-        if vec_size * dtype_bytes < vmem_budget_bytes and vec_size < max_local_index:
+        s, _ = lane_geometry(vec_size)
+        if (s * LANES * dtype_bytes < vmem_budget_bytes
+                and vec_size < max_local_index and s <= MAX_LANE_ROWS):
             return n_parts, vec_size
         k += 1
 

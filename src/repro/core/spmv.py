@@ -175,17 +175,18 @@ class EHYBDevice:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class EHYBPackedDevice:
-    """Device-side packed-staircase EHYB (kernel v2)."""
+    """Device-side packed-staircase EHYB: the Pallas kernel's tables
+    (see :class:`repro.core.ehyb.PackedEHYB` for the tile layout)."""
 
     n: int
     n_pad: int
     n_parts: int
     vec_size: int
     has_er: bool
-    packed_vals: jnp.ndarray    # (P, L)
-    packed_cols: jnp.ndarray    # (P, L) uint16
-    col_starts: jnp.ndarray     # (P, W+1) int32
-    col_rows: jnp.ndarray       # (P, W) int32
+    packed_vals: jnp.ndarray    # (P, T, Sb, 128)
+    packed_cols: jnp.ndarray    # (P, T, Sb, 128) uint16
+    col_starts: jnp.ndarray     # (P, W+1) int32 first tile of column k
+    col_rows: jnp.ndarray       # (P, W) int32 active rows of column k
     er_vals: jnp.ndarray
     er_cols: jnp.ndarray
     er_row_idx: jnp.ndarray
@@ -299,10 +300,10 @@ def _from_permuted(obj, y_new: jnp.ndarray, squeeze: bool) -> jnp.ndarray:
 
 
 def _fused_er_parts(x_new, er_p_vals, er_p_cols, er_p_rows, vec_size):
-    """Per-partition ER contribution in (P, V, R) layout — the transparent
-    form of the fused megakernel's ER stage: each partition gathers its own
-    ER rows from the (VMEM-resident) full x and scatters them LOCALLY into
-    its (V, R) output block.  No global scatter-add."""
+    """Per-partition ER contribution in (P, V, R) layout: each partition
+    gathers its own ER rows from the full x and scatters them LOCALLY into
+    its (V, R) output block.  No global scatter-add.  The XLA formats and
+    the Pallas format (whose kernel covers only the cached part) share it."""
     R = x_new.shape[1]
 
     def one_part(vals, cols, rows):
@@ -318,8 +319,7 @@ def ehyb_spmv_permuted(m: EHYBDevice, x_new: jnp.ndarray) -> jnp.ndarray:
     """EHYB SpMV/SpMM in the permuted space: x_new, y_new are (n_pad[, R]).
 
     The hot-loop form: no pad, no ``perm``/``inv_perm`` gathers, ER fused
-    into the per-partition accumulation (oracle for the fused Pallas
-    megakernel)."""
+    into the per-partition accumulation."""
     x2, squeeze = _as_2d(x_new)
     R = x2.shape[1]
     x_parts = x2.reshape(m.n_parts, m.vec_size, R)

@@ -1,35 +1,21 @@
 """Pallas TPU kernels for the paper's compute hot-spot: EHYB SpMV/SpMM.
 
-ehyb_spmv.py   — pl.pallas_call kernels with explicit BlockSpec VMEM tiling
-                 (partition ↔ grid step; x-slice ↔ VMEM block), including the
-                 fused megakernels (sliced-ELL + per-partition ER in one
-                 launch).
-ehyb_spmm.py   — multi-rhs (n_pad, K) siblings: each A tile and the cached
-                 x-tile are loaded once and reused across all K rhs columns
-                 via a k-chunked accumulator sweep.
+ehyb_spmv.py   — the packed-staircase kernel: lane-dense x-slice cached in
+                 VMEM per partition, uint16 local columns gathered inside
+                 the vreg; one kernel for one rhs and for a (n_pad, K) batch.
 ops.py         — jit'd public wrappers (interpret=True on CPU); the
-                 ``*_permuted`` variants are the solver hot-loop entry points
-                 and route to the SpMM megakernels when the rhs is a batch.
+                 ``*_permuted`` variant is the solver hot-loop entry point.
 solver_step.py — fused CG vector-update kernel (axpy + preconditioner apply
                  + both dot reductions in one HBM pass).
 ref.py         — pure-jnp oracles used by the allclose test sweeps.
 """
 
-from .ehyb_spmv import (ehyb_ell_pallas, ehyb_ell_packed_pallas,
-                        ehyb_fused_pallas, ehyb_packed_fused_pallas,
-                        er_pallas)
-from .ehyb_spmm import (ehyb_ell_packed_spmm_pallas, ehyb_ell_spmm_pallas,
-                        ehyb_fused_spmm_pallas, ehyb_packed_fused_spmm_pallas)
-from .ops import (ehyb_ell_only_pallas, ehyb_spmv_packed_pallas,
-                  ehyb_spmv_packed_pallas_permuted, ehyb_spmv_pallas,
-                  ehyb_spmv_pallas_permuted)
+from .ehyb_spmv import ehyb_packed_pallas
+from .ops import (backend_supports_pallas, ehyb_spmv_packed_pallas,
+                  ehyb_spmv_packed_pallas_permuted)
 from .solver_step import fused_cg_update
 from . import ref
 
-__all__ = ["ehyb_ell_pallas", "ehyb_ell_packed_pallas", "ehyb_fused_pallas",
-           "ehyb_packed_fused_pallas", "er_pallas",
-           "ehyb_ell_packed_spmm_pallas", "ehyb_ell_spmm_pallas",
-           "ehyb_fused_spmm_pallas", "ehyb_packed_fused_spmm_pallas",
-           "ehyb_ell_only_pallas", "ehyb_spmv_packed_pallas",
-           "ehyb_spmv_packed_pallas_permuted", "ehyb_spmv_pallas",
-           "ehyb_spmv_pallas_permuted", "fused_cg_update", "ref"]
+__all__ = ["ehyb_packed_pallas", "backend_supports_pallas",
+           "ehyb_spmv_packed_pallas", "ehyb_spmv_packed_pallas_permuted",
+           "fused_cg_update", "ref"]

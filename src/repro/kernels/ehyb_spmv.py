@@ -1,29 +1,30 @@
 """Pallas TPU kernel for EHYB SpMV/SpMM — the paper's CUDA kernel (Algo 3),
 re-derived for the TPU memory hierarchy.
 
-Mapping (DESIGN.md §2):
+Mapping from the paper's GPU kernel:
 
-  CUDA block ↔ grid step ``p`` (one partition per step).
-  shared-memory x-slice ↔ the ``x_parts`` BlockSpec block ``(1, V, R)``:
-      Mosaic DMAs partition p's x-slice HBM→VMEM once per step and
-      double-buffers step p+1's slice during step p's compute — the TPU form
-      of "explicit caching" *plus* the overlap the GPU gets from warp
-      switching.
-  warp slice (32 rows) ↔ the VPU processes the whole (V, Wc) tile; the
-      8-sublane × 128-lane vregs replace SIMT lanes, and the in-partition
-      row-length sort (done at format build) keeps tiles tight.
-  uint16 col idx ↔ identical: the (1, V, W) uint16 block is the dominant
-      HBM stream; widened to int32 in-register before the VMEM gather.
+  CUDA block ↔ grid step (``B`` partitions per step).
+  shared-memory x-slice ↔ the ``(B, Rc, S, 128)`` x block: partition p's
+      V = S·128 cached x entries, lane-dense (local column c sits at lane-row
+      c // 128, lane c % 128).  Mosaic DMAs it HBM→VMEM once per step and
+      double-buffers the next step's slice during this step's compute.
+  warp slice (32 rows) ↔ one ``(Sb, 128)`` tile of the packed staircase:
+      Sb·128 consecutive rows of one sliced-ELL column (Sb = 8 sublanes, or
+      S when a partition has fewer lane-rows).
+  uint16 col idx ↔ identical: the uint16 tile stream is the dominant HBM
+      stream; widened to int32 in-register.
   atomic slice scheduler ↔ dropped (static grid; balance comes from the
-      nnz-balanced partitioner + width bucketing) — see DESIGN.md §7.
+      nnz-balanced partitioner).
 
-The inner loop chunks W so the gathered ``(V, Wc, R)`` intermediate stays
-inside a VMEM budget; ``W`` is static so chunking unrolls at trace time.
+The VMEM gather.  Mosaic gathers only inside one vreg
+(``tpu.dynamic_gather``: ``take_along_axis`` along the 128 lanes of a row).
+A local column index c splits into (hi, lo) = (c >> 7, c & 127): for every
+lane-row j of the x-slice the kernel broadcasts row j over the tile, gathers
+lanes with ``lo`` and keeps the lanes whose ``hi == j``.  That costs S
+gather+select pairs per tile, which is why ``choose_vec_size`` caps S.
 
-These kernels carry a single rhs (or a thin trailing R used by the solver's
-blocked probes); the batched (n_pad, K) serving path lives in the sibling
-``ehyb_spmm`` module, which reuses ``_w_chunk`` and ``_er_stage`` and adds a
-k-chunked accumulator sweep over the rhs columns.
+The ER ("extra rows") remainder is not in this kernel: it is computed by XLA
+(``core.spmv._fused_er_parts``) and added by the wrapper in ``ops.py``.
 """
 
 from __future__ import annotations
@@ -33,310 +34,130 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# VMEM working-set budget for the gathered intermediate (bytes).  v5e VMEM is
-# ~128 MiB; we keep the scratch tile well under it so the x-slice block, the
-# val/col blocks and double-buffering all fit comfortably.  This default is a
-# *tunable* parameter (repro.tuning SEARCH_SPACE "gather_budget"): plan-level
-# tuned values arrive via each wrapper's ``gather_budget`` kwarg.
+from ..core.partition import LANES, lane_geometry
+
+# VMEM bytes of packed value+column tiles one grid step streams in (sizes how
+# many partitions a step handles).  Tunable (repro.tuning SEARCH_SPACE
+# "gather_budget") through the wrapper's ``gather_budget`` kwarg.
 _GATHER_BUDGET = 4 * 1024 * 1024
+# rhs columns per grid step of a multi-rhs apply (SpMM).  Tunable
+# ("rhs_chunk") through the wrapper's ``rhs_chunk`` kwarg.
+_RHS_CHUNK = 16
 
 
-def _w_chunk(v: int, w: int, r: int, itemsize: int,
-             budget: int | None = None) -> int:
-    per_col = v * r * itemsize
-    b = _GATHER_BUDGET if budget is None else budget
-    return max(1, min(w, b // max(per_col, 1)))
+def _largest_divisor_at_most(n: int, cap: int) -> int:
+    for d in range(max(1, min(n, cap)), 0, -1):
+        if n % d == 0:
+            return d
+    return 1
 
 
-def _ehyb_ell_kernel(x_ref, vals_ref, cols_ref, y_ref, *, w_chunk: int):
-    """One grid step == one partition (the paper's CUDA block)."""
-    x = x_ref[0]                              # (V, R)  — the explicit cache
-    vals = vals_ref[0]                        # (V, W)
-    cols = cols_ref[0]                        # (V, W) uint16/int32 local
-    v, w = vals.shape
-    r = x.shape[1]
-    acc = jnp.zeros((v, r), dtype=jnp.float32)
-    for k0 in range(0, w, w_chunk):           # static unroll over W chunks
-        k1 = min(k0 + w_chunk, w)
-        c = cols[:, k0:k1].astype(jnp.int32)  # widen in-register
-        g = jnp.take(x, c, axis=0)            # (V, Wc, R) gather from VMEM
-        acc = acc + jnp.sum(vals[:, k0:k1, None].astype(jnp.float32)
-                            * g.astype(jnp.float32), axis=1)
-    y_ref[0] = acc.astype(y_ref.dtype)
+def _gather_row(x_ref, b, r, hi, lo, s: int):
+    """x[b, r][c] for every local column c = hi·128 + lo of one tile."""
+    out = jnp.zeros(lo.shape, jnp.float32)
+    for j in range(s):                        # static: one pass per lane-row
+        row = jnp.broadcast_to(x_ref[b, r, j:j + 1, :].astype(jnp.float32),
+                               lo.shape)
+        out = jnp.where(hi == j, jnp.take_along_axis(row, lo, axis=1), out)
+    return out
 
 
-def ehyb_ell_pallas(x_parts: jnp.ndarray, ell_vals: jnp.ndarray,
-                    ell_cols: jnp.ndarray, *, interpret: bool = True,
-                    gather_budget: int | None = None) -> jnp.ndarray:
-    """Cached (sliced-ELL) part: y_parts (P, V, R) = EHYB_ELL(x_parts).
+def _ehyb_packed_kernel(starts_ref, rows_ref, x_ref, vals_ref, cols_ref,
+                        y_ref, acc_ref, *, n_cols: int, s: int, sb: int):
+    """One grid step = B partitions × Rc rhs columns.
 
-    x_parts:  (P, V, R) reordered input, partition-major
-    ell_vals: (P, V, W)
-    ell_cols: (P, V, W) uint16 (paper §3.4) or int32 local indices
+    Column k of partition b holds rows [0, R_k) (rows are width-sorted at
+    format build), stored as ceil(R_k / (Sb·128)) tiles from tile
+    ``starts[b, k]``; tile t of the column feeds output lane-rows
+    [t·Sb, (t+1)·Sb)."""
+    tile = sb * LANES
+    n_b, n_r = x_ref.shape[0], x_ref.shape[1]
+
+    def part(b, carry):
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def column(k, c):
+            start = starts_ref[0, b, k]
+            n_tiles = (rows_ref[0, b, k] + (tile - 1)) // tile
+
+            def one_tile(t, cc):
+                v = vals_ref[b, start + t].astype(jnp.float32)    # (Sb, 128)
+                idx = cols_ref[b, start + t].astype(jnp.int32)    # widen
+                hi = idx >> 7
+                lo = idx & (LANES - 1)
+                off = pl.multiple_of(t * sb, sb)
+                for r in range(n_r):
+                    g = _gather_row(x_ref, b, r, hi, lo, s)
+                    acc_ref[r, pl.ds(off, sb), :] += v * g
+                return cc
+
+            return jax.lax.fori_loop(0, n_tiles, one_tile, c)
+
+        jax.lax.fori_loop(0, n_cols, column, 0)
+        y_ref[b] = acc_ref[...].astype(y_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, n_b, part, 0)
+
+
+def ehyb_packed_pallas(x_parts: jnp.ndarray, packed_vals: jnp.ndarray,
+                       packed_cols: jnp.ndarray, col_starts: jnp.ndarray,
+                       col_rows: jnp.ndarray, *, interpret: bool = False,
+                       gather_budget: int | None = None,
+                       rhs_chunk: int | None = None) -> jnp.ndarray:
+    """Cached (sliced-ELL) part of EHYB SpMV/SpMM: y_parts (P, V, R).
+
+    x_parts:      (P, V, R) permuted input, partition-major
+    packed_vals:  (P, T, Sb, 128) value tiles (see ``core.ehyb.pack_staircase``)
+    packed_cols:  (P, T, Sb, 128) uint16 local column tiles
+    col_starts:   (P, W+1) int32 first tile of column k
+    col_rows:     (P, W) int32 active rows R_k of column k
     """
     p, v, r = x_parts.shape
-    _, _, w = ell_vals.shape
-    w_chunk = _w_chunk(v, w, r, x_parts.dtype.itemsize, gather_budget)
-    kernel = functools.partial(_ehyb_ell_kernel, w_chunk=w_chunk)
-    return pl.pallas_call(
-        kernel,
-        grid=(p,),
-        in_specs=[
-            pl.BlockSpec((1, v, r), lambda i: (i, 0, 0)),   # x-slice → VMEM
-            pl.BlockSpec((1, v, w), lambda i: (i, 0, 0)),   # values
-            pl.BlockSpec((1, v, w), lambda i: (i, 0, 0)),   # local cols
-        ],
-        out_specs=pl.BlockSpec((1, v, r), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, v, r), x_parts.dtype),
-        interpret=interpret,
-    )(x_parts, ell_vals, ell_cols)
-
-
-def _ehyb_packed_kernel(x_ref, vals_ref, cols_ref, starts_ref, rows_ref,
-                        y_ref, *, w: int, v: int):
-    """Kernel v2: column-major staircase packing (paper's sliced-ELL bytes).
-
-    Per grid step (= partition, as in v1): the packed value/col streams carry
-    no inter-slice padding; column k is a contiguous segment of R_k entries
-    covering rows [0, R_k).  Loads are static-length (V) at dynamic offsets
-    (over-read into the +V guard region, masked by R_k), so Mosaic sees
-    fixed-shape vector ops."""
-    x = x_ref[0]                                   # (V, R) cached slice
-    r = x.shape[1]
-    acc = jnp.zeros((v, r), dtype=jnp.float32)
-    row_iota = jax.lax.iota(jnp.int32, v)
-    for k in range(w):                             # static unroll over columns
-        off = starts_ref[0, k]
-        rk = rows_ref[0, k]
-        # leading index must be a Slice: jax<=0.4 interpret-mode discharge
-        # chokes on a bare python-int indexer
-        vals = pl.load(vals_ref, (pl.dslice(0, 1), pl.dslice(off, v)))[0]
-        cols = pl.load(cols_ref, (pl.dslice(0, 1), pl.dslice(off, v)))[0]
-        mask = row_iota < rk
-        g = jnp.take(x, cols.astype(jnp.int32), axis=0)        # (V, R)
-        contrib = jnp.where(mask, vals.astype(jnp.float32),
-                            0.0)[:, None] * g.astype(jnp.float32)
-        # column k's segment covers rows [0, R_k) in row order
-        acc = acc + contrib
-    y_ref[0] = acc.astype(y_ref.dtype)
-
-
-def ehyb_ell_packed_pallas(x_parts: jnp.ndarray, packed_vals: jnp.ndarray,
-                           packed_cols: jnp.ndarray, col_starts: jnp.ndarray,
-                           col_rows: jnp.ndarray, *, interpret: bool = True
-                           ) -> jnp.ndarray:
-    """Cached part, packed layout: y_parts (P, V, R).
-
-    packed_vals/cols: (P, L); col_starts: (P, W+1); col_rows: (P, W)."""
-    p, v, r = x_parts.shape
-    l = packed_vals.shape[1]
+    _, t, sb, lanes = packed_vals.shape
+    s, sb_x = lane_geometry(v)
+    if lanes != LANES or sb != sb_x:
+        raise ValueError(f"packed tiles {packed_vals.shape[1:]} do not match "
+                         f"the lane geometry of vec_size={v}")
     w = col_rows.shape[1]
-    kernel = functools.partial(_ehyb_packed_kernel, w=w, v=v)
-    return pl.pallas_call(
+    vl = s * LANES
+    # lane-dense relayout: (P, V, R) -> (P, R, S, 128)
+    xl = jnp.pad(x_parts, ((0, 0), (0, vl - v), (0, 0)))
+    xl = jnp.transpose(xl, (0, 2, 1)).reshape(p, r, s, LANES)
+    rc = max(1, min(r, _RHS_CHUNK if rhs_chunk is None else rhs_chunk))
+    r_pad = -(-r // rc) * rc
+    if r_pad != r:
+        xl = jnp.pad(xl, ((0, 0), (0, r_pad - r), (0, 0), (0, 0)))
+    budget = _GATHER_BUDGET if gather_budget is None else gather_budget
+    per_part = t * sb * LANES * (packed_vals.dtype.itemsize
+                                 + packed_cols.dtype.itemsize)
+    nb = _largest_divisor_at_most(p, budget // max(per_part, 1))
+    x_bytes = nb * rc * vl * 4
+    vmem = 2 * (nb * per_part + 2 * x_bytes) + rc * vl * 4 + (8 << 20)
+    kernel = functools.partial(_ehyb_packed_kernel, n_cols=w, s=s, sb=sb)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    y = pl.pallas_call(
         kernel,
-        grid=(p,),
+        grid=(p // nb, r_pad // rc),
         in_specs=[
-            pl.BlockSpec((1, v, r), lambda i: (i, 0, 0)),    # x-slice cache
-            pl.BlockSpec((1, l), lambda i: (i, 0)),          # packed values
-            pl.BlockSpec((1, l), lambda i: (i, 0)),          # packed cols
-            pl.BlockSpec((1, w + 1), lambda i: (i, 0)),      # col offsets
-            pl.BlockSpec((1, w), lambda i: (i, 0)),          # col row counts
+            smem((1, nb, w + 1), lambda i, c: (i, 0, 0)),          # starts
+            smem((1, nb, w), lambda i, c: (i, 0, 0)),              # R_k
+            pl.BlockSpec((nb, rc, s, LANES), lambda i, c: (i, c, 0, 0)),
+            pl.BlockSpec((nb, t, sb, LANES), lambda i, c: (i, 0, 0, 0)),
+            pl.BlockSpec((nb, t, sb, LANES), lambda i, c: (i, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, v, r), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, v, r), x_parts.dtype),
+        out_specs=pl.BlockSpec((nb, rc, s, LANES),
+                               lambda i, c: (i, c, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((p, r_pad, s, LANES), x_parts.dtype),
+        scratch_shapes=[pltpu.VMEM((rc, s, LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=min(vmem, 100 << 20)),
         interpret=interpret,
-    )(x_parts, packed_vals, packed_cols, col_starts, col_rows)
-
-
-def _er_stage(acc, xf, erv, erc, rows, v: int, e_chunk: int):
-    """Fused-ER stage shared by the megakernels: partition p's ER rows gather
-    from the VMEM-resident full x and accumulate into p's own (V, R) block.
-
-    The local scatter is a one-hot (V, E) × (E, R) contraction — static
-    shapes, MXU-friendly, no read-modify-write of the output in HBM."""
-    e_, we = erv.shape
-    r = xf.shape[1]
-    er_acc = jnp.zeros((e_, r), dtype=jnp.float32)
-    for k0 in range(0, we, e_chunk):          # static unroll over We chunks
-        k1 = min(k0 + e_chunk, we)
-        g = jnp.take(xf, erc[:, k0:k1], axis=0)         # (E, Wc, R)
-        er_acc = er_acc + jnp.sum(erv[:, k0:k1, None].astype(jnp.float32)
-                                  * g.astype(jnp.float32), axis=1)
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (1, v), 1)
-    onehot = (rows[:, None] == row_iota).astype(jnp.float32)   # (E, V)
-    return acc + jnp.dot(onehot.T, er_acc,
-                         preferred_element_type=jnp.float32)
-
-
-def _ehyb_fused_kernel(x_ref, xfull_ref, vals_ref, cols_ref, erv_ref,
-                       erc_ref, err_ref, y_ref, *, w_chunk: int,
-                       e_chunk: int):
-    """Megakernel: one grid step = one partition computes its sliced-ELL tile
-    AND its own ER rows into the same (V, R) output block — one pallas_call
-    per SpMV, no second launch, no caller-side scatter-add."""
-    x = x_ref[0]                              # (V, R)  — the explicit cache
-    vals = vals_ref[0]                        # (V, W)
-    cols = cols_ref[0]                        # (V, W) uint16/int32 local
-    v, w = vals.shape
-    r = x.shape[1]
-    acc = jnp.zeros((v, r), dtype=jnp.float32)
-    for k0 in range(0, w, w_chunk):           # static unroll over W chunks
-        k1 = min(k0 + w_chunk, w)
-        c = cols[:, k0:k1].astype(jnp.int32)  # widen in-register
-        g = jnp.take(x, c, axis=0)            # (V, Wc, R) gather from VMEM
-        acc = acc + jnp.sum(vals[:, k0:k1, None].astype(jnp.float32)
-                            * g.astype(jnp.float32), axis=1)
-    acc = _er_stage(acc, xfull_ref[...], erv_ref[0], erc_ref[0],
-                    err_ref[0], v, e_chunk)
-    y_ref[0] = acc.astype(y_ref.dtype)
-
-
-def ehyb_fused_pallas(x_new: jnp.ndarray, ell_vals: jnp.ndarray,
-                      ell_cols: jnp.ndarray, er_p_vals: jnp.ndarray,
-                      er_p_cols: jnp.ndarray, er_p_rows: jnp.ndarray,
-                      *, interpret: bool = True,
-                      gather_budget: int | None = None) -> jnp.ndarray:
-    """Fused EHYB SpMV in the permuted space: y_new (n_pad, R).
-
-    x_new:              (n_pad, R) permuted input (viewed both as per-
-                        partition slices and as the resident full block the
-                        ER gathers hit)
-    ell_vals/ell_cols:  (P, V, W)
-    er_p_vals/er_p_cols: (P, E, We) per-partition ER tiles
-    er_p_rows:          (P, E) local row of each ER slot
-    """
-    n_pad, r = x_new.shape
-    p, v, w = ell_vals.shape
-    _, e, we = er_p_vals.shape
-    x_parts = x_new.reshape(p, v, r)
-    w_chunk = _w_chunk(v, w, r, x_new.dtype.itemsize, gather_budget)
-    e_chunk = _w_chunk(e, we, r, x_new.dtype.itemsize, gather_budget)
-    kernel = functools.partial(_ehyb_fused_kernel, w_chunk=w_chunk,
-                               e_chunk=e_chunk)
-    return pl.pallas_call(
-        kernel,
-        grid=(p,),
-        in_specs=[
-            pl.BlockSpec((1, v, r), lambda i: (i, 0, 0)),   # x-slice → VMEM
-            pl.BlockSpec((n_pad, r), lambda i: (0, 0)),     # full x (stays)
-            pl.BlockSpec((1, v, w), lambda i: (i, 0, 0)),   # values
-            pl.BlockSpec((1, v, w), lambda i: (i, 0, 0)),   # local cols
-            pl.BlockSpec((1, e, we), lambda i: (i, 0, 0)),  # ER values
-            pl.BlockSpec((1, e, we), lambda i: (i, 0, 0)),  # ER global cols
-            pl.BlockSpec((1, e), lambda i: (i, 0)),         # ER local rows
-        ],
-        out_specs=pl.BlockSpec((1, v, r), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, v, r), x_new.dtype),
-        interpret=interpret,
-    )(x_parts, x_new, ell_vals, ell_cols, er_p_vals, er_p_cols,
-      er_p_rows).reshape(n_pad, r)
-
-
-def _ehyb_packed_fused_kernel(x_ref, xfull_ref, vals_ref, cols_ref,
-                              starts_ref, rows_ref, erv_ref, erc_ref,
-                              err_ref, y_ref, *, w: int, v: int,
-                              e_chunk: int):
-    """Packed-staircase megakernel: kernel v2's column-segment loop plus the
-    fused ER stage, one launch per SpMV."""
-    x = x_ref[0]                                   # (V, R) cached slice
-    r = x.shape[1]
-    acc = jnp.zeros((v, r), dtype=jnp.float32)
-    row_iota = jax.lax.iota(jnp.int32, v)
-    for k in range(w):                             # static unroll over columns
-        off = starts_ref[0, k]
-        rk = rows_ref[0, k]
-        vals = pl.load(vals_ref, (pl.dslice(0, 1), pl.dslice(off, v)))[0]
-        cols = pl.load(cols_ref, (pl.dslice(0, 1), pl.dslice(off, v)))[0]
-        mask = row_iota < rk
-        g = jnp.take(x, cols.astype(jnp.int32), axis=0)        # (V, R)
-        contrib = jnp.where(mask, vals.astype(jnp.float32),
-                            0.0)[:, None] * g.astype(jnp.float32)
-        acc = acc + contrib
-    acc = _er_stage(acc, xfull_ref[...], erv_ref[0], erc_ref[0],
-                    err_ref[0], v, e_chunk)
-    y_ref[0] = acc.astype(y_ref.dtype)
-
-
-def ehyb_packed_fused_pallas(x_new: jnp.ndarray, packed_vals: jnp.ndarray,
-                             packed_cols: jnp.ndarray,
-                             col_starts: jnp.ndarray, col_rows: jnp.ndarray,
-                             er_p_vals: jnp.ndarray, er_p_cols: jnp.ndarray,
-                             er_p_rows: jnp.ndarray, *, vec_size: int,
-                             interpret: bool = True,
-                             gather_budget: int | None = None) -> jnp.ndarray:
-    """Fused packed EHYB SpMV in the permuted space: y_new (n_pad, R)."""
-    n_pad, r = x_new.shape
-    p, l = packed_vals.shape
-    w = col_rows.shape[1]
-    v = vec_size
-    _, e, we = er_p_vals.shape
-    x_parts = x_new.reshape(p, v, r)
-    e_chunk = _w_chunk(e, we, r, x_new.dtype.itemsize, gather_budget)
-    kernel = functools.partial(_ehyb_packed_fused_kernel, w=w, v=v,
-                               e_chunk=e_chunk)
-    return pl.pallas_call(
-        kernel,
-        grid=(p,),
-        in_specs=[
-            pl.BlockSpec((1, v, r), lambda i: (i, 0, 0)),    # x-slice cache
-            pl.BlockSpec((n_pad, r), lambda i: (0, 0)),      # full x (stays)
-            pl.BlockSpec((1, l), lambda i: (i, 0)),          # packed values
-            pl.BlockSpec((1, l), lambda i: (i, 0)),          # packed cols
-            pl.BlockSpec((1, w + 1), lambda i: (i, 0)),      # col offsets
-            pl.BlockSpec((1, w), lambda i: (i, 0)),          # col row counts
-            pl.BlockSpec((1, e, we), lambda i: (i, 0, 0)),   # ER values
-            pl.BlockSpec((1, e, we), lambda i: (i, 0, 0)),   # ER global cols
-            pl.BlockSpec((1, e), lambda i: (i, 0)),          # ER local rows
-        ],
-        out_specs=pl.BlockSpec((1, v, r), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((p, v, r), x_new.dtype),
-        interpret=interpret,
-    )(x_parts, x_new, packed_vals, packed_cols, col_starts, col_rows,
-      er_p_vals, er_p_cols, er_p_rows).reshape(n_pad, r)
-
-
-def _er_kernel(x_ref, vals_ref, cols_ref, y_ref, *, w_chunk: int):
-    """ER part: same dot-row structure but the gather hits the FULL x block
-    (uncached in the paper's sense — on TPU, a VMEM-resident copy of x that is
-    streamed once for all ER tiles rather than per-partition)."""
-    x = x_ref[...]                            # (n_pad, R)
-    vals = vals_ref[0]                        # (T, W)
-    cols = cols_ref[0]                        # (T, W) int32 global
-    t, w = vals.shape
-    r = x.shape[1]
-    acc = jnp.zeros((t, r), dtype=jnp.float32)
-    for k0 in range(0, w, w_chunk):
-        k1 = min(k0 + w_chunk, w)
-        g = jnp.take(x, cols[:, k0:k1], axis=0)
-        acc = acc + jnp.sum(vals[:, k0:k1, None].astype(jnp.float32)
-                            * g.astype(jnp.float32), axis=1)
-    y_ref[0] = acc.astype(y_ref.dtype)
-
-
-def er_pallas(x_new: jnp.ndarray, er_vals: jnp.ndarray, er_cols: jnp.ndarray,
-              *, row_tile: int = 256, interpret: bool = True,
-              gather_budget: int | None = None) -> jnp.ndarray:
-    """ER rows → per-slot partial sums (Rr, R); caller scatter-adds."""
-    n_pad, r = x_new.shape
-    rr, w = er_vals.shape
-    row_tile = min(row_tile, rr)
-    while rr % row_tile:
-        row_tile //= 2
-    row_tile = max(row_tile, 1)
-    grid = (rr // row_tile,)
-    w_chunk = _w_chunk(row_tile, w, r, x_new.dtype.itemsize, gather_budget)
-    kernel = functools.partial(_er_kernel, w_chunk=w_chunk)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n_pad, r), lambda i: (0, 0)),      # full x (stays)
-            pl.BlockSpec((1, row_tile, w), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, row_tile, w), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, row_tile, r), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], row_tile, r), x_new.dtype),
-        interpret=interpret,
-    )(x_new, er_vals.reshape(grid[0], row_tile, w),
-      er_cols.reshape(grid[0], row_tile, w)).reshape(rr, r)
+        name="ehyb_packed_spmv",
+    )(col_starts.reshape(p // nb, nb, w + 1), col_rows.reshape(p // nb, nb, w),
+      xl, packed_vals, packed_cols)
+    y = y[:, :r].reshape(p, r, vl)
+    return jnp.transpose(y, (0, 2, 1))[:, :v, :]

@@ -1,14 +1,15 @@
-"""Jit'd public wrappers around the Pallas EHYB kernels.
+"""Jit'd public wrappers around the Pallas EHYB kernel.
 
 ``interpret=None`` (default) resolves per backend: the Pallas interpreter
 on CPU (exact, for validation), compiled through Mosaic on TPU.  Pass an
 explicit bool to override.
 
-The hot path is ONE pallas_call per SpMV: the fused megakernel computes the
-sliced-ELL tile and the partition's own ER rows into the same (V, R) output
-block (ER slots were grouped by owning partition at format build).  The
-``*_permuted`` variants consume/produce permuted-space vectors so solver
-loops skip the per-call pad/``perm``/``inv_perm`` gathers entirely.
+One apply = one ``pallas_call`` for the cached sliced-ELL part plus the ER
+remainder in XLA (``core.spmv._fused_er_parts``), accumulated into the same
+per-partition (V, R) block.  The ``*_permuted`` variant consumes/produces
+permuted-space vectors so solver loops skip the per-call pad/``perm``/
+``inv_perm`` gathers entirely; an (n_pad, K) rhs runs the same kernel with
+the A tiles streamed once for all K columns.
 """
 
 from __future__ import annotations
@@ -16,18 +17,9 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
-from ..core.spmv import (EHYBDevice, _as_2d, _from_permuted, _fused_er_parts,
-                         _to_permuted)
-from . import ehyb_spmm as _km
+from ..core.spmv import _as_2d, _from_permuted, _fused_er_parts, _to_permuted
 from . import ehyb_spmv as _k
-
-# Rhs width at which the *_permuted wrappers route to the SpMM megakernels
-# (k-chunked accumulators, x-tile loaded once for all rhs) instead of the
-# SpMV kernels.  Static at trace time — the dispatch costs nothing at run
-# time and each width compiles its own specialized kernel.
-_SPMM_MIN_RHS = 2
 
 
 def _resolve_interpret(interpret):
@@ -53,10 +45,14 @@ def backend_supports_pallas(backend: str | None = None) -> bool:
     Cached per (backend, chaos epoch): ``reliability.chaos`` can force the
     probe to fail — and its epoch bump on exit re-arms the real answer.
     A False here short-circuits every Pallas level of the guarded-apply
-    fallback chain without paying one doomed compile per plan."""
+    fallback chain without paying one doomed compile per plan.  On a TPU
+    with no fault injection armed a failure is never an answer: the probe
+    raises, since Pallas is the path the format promises there."""
+    import jax.numpy as jnp
     import numpy as np
 
     # function imports (the package attr `chaos` shadows the submodule)
+    from ..reliability.chaos import active as _chaos_active
     from ..reliability.chaos import check_kernel as _chaos_check
     from ..reliability.chaos import epoch as _chaos_epoch
 
@@ -65,94 +61,45 @@ def backend_supports_pallas(backend: str | None = None) -> bool:
     hit = _PALLAS_OK.get(key)
     if hit is not None:
         return hit
-    try:
-        _chaos_check("pallas:probe")
+
+    def probe():
         from jax.experimental import pallas as pl
 
         def _double(x_ref, o_ref):
             o_ref[...] = x_ref[...] * 2.0
 
-        x = jnp.arange(8, dtype=jnp.float32)
+        x = jnp.arange(8 * 128, dtype=jnp.float32).reshape(8, 128)
         y = pl.pallas_call(
-            _double, out_shape=jax.ShapeDtypeStruct((8,), jnp.float32),
+            _double, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
             interpret=_resolve_interpret(None))(x)
-        ok = bool(np.allclose(np.asarray(jax.block_until_ready(y)),
-                              np.arange(8, dtype=np.float32) * 2.0))
-    except Exception:  # noqa: BLE001 — capability probe: ANY lowering,
+        return bool(np.allclose(np.asarray(jax.block_until_ready(y)),
+                                np.asarray(x) * 2.0))
+
+    try:
+        _chaos_check("pallas:probe")
+        from ..api.plan import _run_untraced
+
+        # concrete values even when the guard resolves inside a trace
+        ok = _run_untraced(probe)
+    except Exception as e:  # noqa: BLE001 — capability probe: ANY lowering,
         # compile or execution error (jax raises many types) means pallas
-        # is unusable on this backend; the probe's answer is simply False
+        # is unusable on this backend
+        if backend == "tpu" and _chaos_active() is None:
+            raise RuntimeError(f"pallas probe failed on the TPU: {e}") from e
         ok = False
+    if not ok and backend == "tpu" and _chaos_active() is None:
+        raise RuntimeError("pallas probe returned wrong values on the TPU")
     _PALLAS_OK[key] = ok
     return ok
 
 
-@partial(jax.jit, static_argnames=("interpret", "use_er_kernel"))
-def ehyb_spmv_pallas_permuted(m: EHYBDevice, x_new: jnp.ndarray, *,
-                              interpret: bool | None = None,
-                              use_er_kernel: bool = True) -> jnp.ndarray:
-    """Permuted-space EHYB SpMV/SpMM: x_new (n_pad,) or (n_pad, R).
-
-    ``use_er_kernel=True`` (default) runs the fused megakernel — one
-    pallas_call computing ELL + ER; ``False`` keeps the ELL-only kernel and
-    adds the ER contribution with the jnp per-partition path (validation
-    fallback).  ER-free matrices skip the ER stage statically either way.
-    """
-    interpret = _resolve_interpret(interpret)
-    x2, squeeze = _as_2d(x_new)
-    spmm = x2.shape[1] >= _SPMM_MIN_RHS
-    if m.has_er and use_er_kernel:
-        fused = _km.ehyb_fused_spmm_pallas if spmm else _k.ehyb_fused_pallas
-        y_new = fused(x2, m.ell_vals, m.ell_cols,
-                      m.er_p_vals, m.er_p_cols, m.er_p_rows,
-                      interpret=interpret)
-    else:
-        x_parts = x2.reshape(m.n_parts, m.vec_size, x2.shape[1])
-        ell = _km.ehyb_ell_spmm_pallas if spmm else _k.ehyb_ell_pallas
-        y_parts = ell(x_parts, m.ell_vals, m.ell_cols, interpret=interpret)
-        if m.has_er:
-            y_parts = y_parts + _fused_er_parts(
-                x2, m.er_p_vals, m.er_p_cols, m.er_p_rows,
-                m.vec_size).astype(y_parts.dtype)
-        y_new = y_parts.reshape(m.n_pad, x2.shape[1])
-    return y_new[:, 0] if squeeze else y_new
-
-
-@partial(jax.jit, static_argnames=("interpret", "use_er_kernel"))
-def ehyb_spmv_pallas(m: EHYBDevice, x: jnp.ndarray, *,
-                     interpret: bool | None = None,
-                     use_er_kernel: bool = True) -> jnp.ndarray:
-    """Full EHYB SpMV/SpMM in the ORIGINAL space: permute in, one fused
-    pallas_call, un-permute out.  x: (n,) or (n, R); returns matching rank."""
-    x_new, squeeze = _to_permuted(m, x)
-    y_new = ehyb_spmv_pallas_permuted(m, x_new, interpret=interpret,
-                                      use_er_kernel=use_er_kernel)
-    return _from_permuted(m, y_new, squeeze)
-
-
 @partial(jax.jit, static_argnames=("interpret",))
-def ehyb_ell_only_pallas(m: EHYBDevice, x: jnp.ndarray, *,
-                         interpret: bool | None = None) -> jnp.ndarray:
-    """Cached part only (for kernel-level benchmarking/validation)."""
-    interpret = _resolve_interpret(interpret)
-    x_new, _ = _to_permuted(m, x)
-    x_parts = x_new.reshape(m.n_parts, m.vec_size, x_new.shape[1])
-    return _k.ehyb_ell_pallas(x_parts, m.ell_vals, m.ell_cols,
-                              interpret=interpret)
-
-
-@partial(jax.jit, static_argnames=("interpret", "use_er_kernel"))
-def ehyb_spmv_packed_pallas_permuted(m, x_new: jnp.ndarray, *,
-                                     interpret: bool | None = None,
-                                     use_er_kernel: bool = True
-                                     ) -> jnp.ndarray:
-    """Kernel v2 (packed staircase), permuted space, ER fused.
+def ehyb_spmv_packed_pallas_permuted(m, x_new: jax.Array, *,
+                                     interpret: bool | None = None
+                                     ) -> jax.Array:
+    """Packed EHYB SpMV/SpMM in the permuted space.
 
     m: core.spmv.EHYBPackedDevice. x_new: (n_pad,) or (n_pad, R).
-
-    ``use_er_kernel=False`` is the unfused degraded level of the guarded
-    apply's fallback chain: the packed ELL kernel alone plus the jnp
-    per-partition ER path — one fewer fused Pallas stage to lower when a
-    backend rejects the megakernel.
 
     Tuned kernel parameters ride the container's static ``kparams`` aux
     (``repro.tuning.TunedParams.token()``): read here at trace time, they
@@ -161,46 +108,25 @@ def ehyb_spmv_packed_pallas_permuted(m, x_new: jnp.ndarray, *,
     cache entry."""
     interpret = _resolve_interpret(interpret)
     kp = dict(getattr(m, "kparams", ()) or ())
-    gb, rc = kp.get("gather_budget"), kp.get("rhs_chunk")
     x2, squeeze = _as_2d(x_new)
-    spmm = x2.shape[1] >= _SPMM_MIN_RHS
-    if m.has_er and use_er_kernel:
-        if spmm:
-            y_new = _km.ehyb_packed_fused_spmm_pallas(
-                x2, m.packed_vals, m.packed_cols, m.col_starts, m.col_rows,
-                m.er_p_vals, m.er_p_cols, m.er_p_rows, vec_size=m.vec_size,
-                interpret=interpret, rhs_chunk=rc, gather_budget=gb)
-        else:
-            y_new = _k.ehyb_packed_fused_pallas(
-                x2, m.packed_vals, m.packed_cols, m.col_starts, m.col_rows,
-                m.er_p_vals, m.er_p_cols, m.er_p_rows, vec_size=m.vec_size,
-                interpret=interpret, gather_budget=gb)
-    else:
-        x_parts = x2.reshape(m.n_parts, m.vec_size, x2.shape[1])
-        if spmm:
-            y_parts = _km.ehyb_ell_packed_spmm_pallas(
-                x_parts, m.packed_vals, m.packed_cols, m.col_starts,
-                m.col_rows, interpret=interpret, rhs_chunk=rc)
-        else:
-            y_parts = _k.ehyb_ell_packed_pallas(
-                x_parts, m.packed_vals, m.packed_cols, m.col_starts,
-                m.col_rows, interpret=interpret)
-        if m.has_er:
-            y_parts = y_parts + _fused_er_parts(
-                x2, m.er_p_vals, m.er_p_cols, m.er_p_rows,
-                m.vec_size).astype(y_parts.dtype)
-        y_new = y_parts.reshape(m.n_pad, x2.shape[1])
+    r = x2.shape[1]
+    y_parts = _k.ehyb_packed_pallas(
+        x2.reshape(m.n_parts, m.vec_size, r), m.packed_vals, m.packed_cols,
+        m.col_starts, m.col_rows, interpret=interpret,
+        gather_budget=kp.get("gather_budget"), rhs_chunk=kp.get("rhs_chunk"))
+    if m.has_er:
+        y_parts = y_parts + _fused_er_parts(
+            x2, m.er_p_vals, m.er_p_cols, m.er_p_rows,
+            m.vec_size).astype(y_parts.dtype)
+    y_new = y_parts.reshape(m.n_pad, r)
     return y_new[:, 0] if squeeze else y_new
 
 
-@partial(jax.jit, static_argnames=("interpret", "use_er_kernel"))
-def ehyb_spmv_packed_pallas(m, x: jnp.ndarray, *,
-                            interpret: bool | None = None,
-                            use_er_kernel: bool = True) -> jnp.ndarray:
-    """Kernel v2 (packed staircase), original space: full EHYB SpMV/SpMM.
-
-    m: core.spmv.EHYBPackedDevice. x: (n,) or (n, R)."""
+@partial(jax.jit, static_argnames=("interpret",))
+def ehyb_spmv_packed_pallas(m, x: jax.Array, *,
+                            interpret: bool | None = None) -> jax.Array:
+    """Packed EHYB SpMV/SpMM, original space. m: EHYBPackedDevice.
+    x: (n,) or (n, R)."""
     x_new, squeeze = _to_permuted(m, x)
-    y_new = ehyb_spmv_packed_pallas_permuted(m, x_new, interpret=interpret,
-                                             use_er_kernel=use_er_kernel)
+    y_new = ehyb_spmv_packed_pallas_permuted(m, x_new, interpret=interpret)
     return _from_permuted(m, y_new, squeeze)

@@ -36,33 +36,3 @@ def er_ref(x_new: jnp.ndarray, er_vals: jnp.ndarray,
     """
     g = x_new[er_cols]                                # (Rr, W, R)
     return jnp.einsum("ew,ewr->er", er_vals, g)
-
-
-def ehyb_fused_ref(x_new: jnp.ndarray, ell_vals: jnp.ndarray,
-                   ell_cols: jnp.ndarray, er_p_vals: jnp.ndarray,
-                   er_p_cols: jnp.ndarray, er_p_rows: jnp.ndarray
-                   ) -> jnp.ndarray:
-    """Fused megakernel oracle: sliced-ELL + per-partition ER, permuted space.
-
-    x_new: (n_pad, R); ell_vals/cols: (P, V, W); er_p_vals/cols: (P, E, We)
-    with global column indices; er_p_rows: (P, E) local rows.  Returns
-    y_new (n_pad, R)."""
-    p, v, _ = ell_vals.shape
-    r = x_new.shape[1]
-    x_parts = x_new.reshape(p, v, r)
-    y = ehyb_ell_ref(x_parts, ell_vals, ell_cols)
-
-    def one(vals, cols, rows):
-        ye = jnp.einsum("ew,ewr->er", vals, x_new[cols])
-        return jnp.zeros((v, r), dtype=ye.dtype).at[rows].add(ye)
-
-    y = y + jax.vmap(one)(er_p_vals, er_p_cols, er_p_rows)
-    return y.reshape(-1, r)
-
-
-def ell_ref(x: jnp.ndarray, vals: jnp.ndarray, cols: jnp.ndarray) -> jnp.ndarray:
-    """Plain (uncached) ELL SpMV oracle: global gathers.
-
-    x: (n, R); vals/cols: (rows, W). returns (rows, R)."""
-    g = x[cols.astype(jnp.int32)]
-    return jnp.einsum("vw,vwr->vr", vals, g)

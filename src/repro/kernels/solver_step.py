@@ -9,15 +9,17 @@ reductions).  This kernel performs
     z' = minv ⊙ r'            (diagonal preconditioner)
     rz = <r', z'>,  rr = <r', r'>
 
-in a single grid sweep: each step streams one tile of (x, r, p, ap, minv)
-from HBM, writes the updated tile, and accumulates both dot products into a
-revisited (1, 2) output block (TPU grid steps are sequential, so read-
-modify-write accumulation across steps is well-defined — the standard Pallas
-reduction pattern).  ``rr`` carried in solver loop state is what lets the
+in a single grid sweep: the vectors are viewed lane-dense as (rows, 128),
+each step streams one (tile_rows, 128) block of (x, r, p, ap, minv) from HBM,
+writes the updated blocks, and folds both dot products into a revisited
+(2, 8, 128) block of vreg-shaped partial sums (TPU grid steps are
+sequential, so read-modify-write accumulation across steps is well-defined —
+the standard Pallas reduction pattern); the last 2·1024-element reduction is
+left to XLA.  ``rr`` carried in solver loop state is what lets the
 ``while_loop`` condition avoid an extra full-vector norm pass.
 
-Like the SpMV kernels, ``interpret=True`` (CPU default) validates the body;
-on TPU pass ``interpret=False``.
+Like the SpMV kernel, ``interpret=True`` (CPU default) validates the body;
+on TPU it is compiled.
 """
 
 from __future__ import annotations
@@ -27,40 +29,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-_TILE = 2048
+_LANES = 128
+_SUBLANES = 8
+_TILE_ROWS = 512          # (512, 128) f32 blocks: 256 KiB per vector
 
 
 def _cg_update_kernel(alpha_ref, x_ref, r_ref, p_ref, ap_ref, minv_ref,
                       xo_ref, ro_ref, zo_ref, dots_ref):
     i = pl.program_id(0)
     alpha = alpha_ref[0, 0]
-    p = p_ref[0].astype(jnp.float32)
-    ap = ap_ref[0].astype(jnp.float32)
-    xn = x_ref[0].astype(jnp.float32) + alpha * p
-    rn = r_ref[0].astype(jnp.float32) - alpha * ap
-    zn = minv_ref[0].astype(jnp.float32) * rn
-    xo_ref[0] = xn.astype(xo_ref.dtype)
-    ro_ref[0] = rn.astype(ro_ref.dtype)
-    zo_ref[0] = zn.astype(zo_ref.dtype)
+    p = p_ref[...].astype(jnp.float32)
+    ap = ap_ref[...].astype(jnp.float32)
+    xn = x_ref[...].astype(jnp.float32) + alpha * p
+    rn = r_ref[...].astype(jnp.float32) - alpha * ap
+    zn = minv_ref[...].astype(jnp.float32) * rn
+    xo_ref[...] = xn.astype(xo_ref.dtype)
+    ro_ref[...] = rn.astype(ro_ref.dtype)
+    zo_ref[...] = zn.astype(zo_ref.dtype)
 
     @pl.when(i == 0)
     def _init():
-        dots_ref[0, 0] = jnp.zeros((), jnp.float32)
-        dots_ref[0, 1] = jnp.zeros((), jnp.float32)
+        dots_ref[...] = jnp.zeros(dots_ref.shape, jnp.float32)
 
-    dots_ref[0, 0] += jnp.sum(rn * zn)
-    dots_ref[0, 1] += jnp.sum(rn * rn)
-
-
-def _pad_tiles(v: jnp.ndarray, tiles: int, tile: int) -> jnp.ndarray:
-    pad = tiles * tile - v.shape[0]
-    return jnp.concatenate([v, jnp.zeros((pad,), v.dtype)]).reshape(
-        tiles, tile)
+    fold = (-1, _SUBLANES, _LANES)
+    dots_ref[0] += jnp.sum((rn * zn).reshape(fold), axis=0)
+    dots_ref[1] += jnp.sum((rn * rn).reshape(fold), axis=0)
 
 
-def fused_cg_update(x: jnp.ndarray, r: jnp.ndarray, p: jnp.ndarray,
-                    ap: jnp.ndarray, minv: jnp.ndarray, alpha: jnp.ndarray,
+def fused_cg_update(x: jax.Array, r: jax.Array, p: jax.Array,
+                    ap: jax.Array, minv: jax.Array, alpha: jax.Array,
                     *, interpret: bool | None = None):
     """One fused pass: returns (x', r', z', rz, rr).
 
@@ -81,23 +80,32 @@ def fused_cg_update(x: jnp.ndarray, r: jnp.ndarray, p: jnp.ndarray,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _fused_cg_update(x, r, p, ap, minv, alpha, *, interpret: bool):
     n = x.shape[0]
-    tile = min(_TILE, max(8, n))
-    tiles = -(-n // tile)
-    xt, rt, pt, apt, mt = (_pad_tiles(v, tiles, tile)
-                           for v in (x, r, p, ap, minv))
+    rows = -(-n // _LANES)
+    tile = min(_TILE_ROWS, -(-rows // _SUBLANES) * _SUBLANES)
+    tiles = -(-rows // tile)
+    pad = tiles * tile * _LANES - n
+
+    def lanes(v):
+        return jnp.pad(v, (0, pad)).reshape(tiles * tile, _LANES)
+
     alpha2 = jnp.asarray(alpha, jnp.float32).reshape(1, 1)
-    vec_spec = pl.BlockSpec((1, tile), lambda i: (i, 0))
+    vec = pl.BlockSpec((tile, _LANES), lambda i: (i, 0))
+    shape = (tiles * tile, _LANES)
     xn, rn, zn, dots = pl.pallas_call(
         _cg_update_kernel,
         grid=(tiles,),
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0))] + [vec_spec] * 5,
-        out_specs=[vec_spec, vec_spec, vec_spec,
-                   pl.BlockSpec((1, 2), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((tiles, tile), x.dtype),
-                   jax.ShapeDtypeStruct((tiles, tile), r.dtype),
-                   jax.ShapeDtypeStruct((tiles, tile), r.dtype),
-                   jax.ShapeDtypeStruct((1, 2), jnp.float32)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [vec] * 5,
+        out_specs=[vec, vec, vec,
+                   pl.BlockSpec((2, _SUBLANES, _LANES), lambda i: (0, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(shape, x.dtype),
+                   jax.ShapeDtypeStruct(shape, r.dtype),
+                   jax.ShapeDtypeStruct(shape, r.dtype),
+                   jax.ShapeDtypeStruct((2, _SUBLANES, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(alpha2, xt, rt, pt, apt, mt)
+        name="fused_cg_update",
+    )(alpha2, lanes(x), lanes(r), lanes(p), lanes(ap), lanes(minv))
+    dots = jnp.sum(dots, axis=(1, 2))
     return (xn.reshape(-1)[:n], rn.reshape(-1)[:n], zn.reshape(-1)[:n],
-            dots[0, 0], dots[0, 1])
+            dots[0], dots[1])

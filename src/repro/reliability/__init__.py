@@ -12,14 +12,14 @@ matching recovery ladders.  Every rung is *observable* (a
 ``core.counters``) and every ladder terminates in a level that cannot
 fail for the same reason the rung above it did.
 
-1. **Kernel dispatch** (``reliability.guard``).  A Pallas megakernel can
+1. **Kernel dispatch** (``reliability.guard``).  A Pallas kernel can
    fail to lower/compile on a backend, or a backend can have no Pallas
    support at all.  Jitted code cannot ``try/except`` that, so recovery
    lives at host dispatch: ``Plan._raw_apply*`` hands out a
    stable-identity ``_Guard`` that resolves, once per chaos epoch, which
    level of the format's fallback chain actually runs::
 
-       fused megakernel -> unfused Pallas -> lax/gather reference
+       native apply -> lax/gather reference
 
    The probe (``kernels.ops.backend_supports_pallas`` + a concrete
    zero-vector run of the candidate level) happens on the untraced
@@ -27,7 +27,9 @@ fail for the same reason the rung above it did.
    Pure-XLA chains skip probing unless chaos is armed — the <5%
    api-overhead budget of the plan layer is untouched.  The autotuner's
    measured pass wraps each candidate the same way: a failing candidate
-   is skipped (``tune.candidate_failed``), not fatal.
+   is skipped (``tune.candidate_failed``), not fatal.  On a TPU with no
+   fault injection armed none of this degrades: a Pallas failure there
+   raises, from the probe, the guard and the tuner alike.
 
 2. **Solver iteration** (``core.solver`` + ``api.operator``).  Krylov
    loops fail *numerically*: BiCGStab rho/rhat·v breakdown, CG on an

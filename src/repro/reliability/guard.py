@@ -5,15 +5,18 @@ happens at host dispatch: :func:`guarded_apply` wraps a plan's raw
 ``(obj, x) -> y`` closure in a :class:`_Guard` that lazily resolves which
 level of the format's fallback chain actually executes on this backend:
 
-    fused megakernel  ->  unfused Pallas  ->  lax/gather reference
+    native apply  ->  lax/gather reference
 
-* the **native** level is the format's registered apply (the fused Pallas
-  megakernel for ``ehyb_packed``; already-XLA applies for the rest);
-* the **unfused** level is the format's ``fallback`` hook when it has one
-  (packed ELL kernel + jnp fused-ER for ``ehyb_packed``);
+* the **native** level is the format's registered apply (the Pallas kernel
+  for ``ehyb_packed``; already-XLA applies for the rest);
 * the **reference** level is format-independent: gather/scatter-add over
   the plan's COO pattern with values recovered through the probed value
   maps — it lowers anywhere XLA does, so the chain always terminates.
+
+On a TPU with no fault injection armed there is no fallback: a native
+level that fails to lower or run raises.  The TPU is where the format's
+kernel is the point, and a silent reference apply under the format's name
+would hide exactly that failure (and time the wrong code in a benchmark).
 
 Resolution probes a level by running it once on the plan's concrete
 template container with a zero vector (on the ``_run_untraced`` worker, so
@@ -96,15 +99,16 @@ def fallback_chain(plan, tpl, kind: str):
     """Ordered ``(name, fn, needs_pallas)`` levels for ``plan``/``kind``."""
     from ..autotune.registry import get_format
 
-    spec = get_format(plan.format)
     native = tpl.apply if kind == "apply" else tpl.apply_permuted
-    pallas_native = spec.kernel != "xla"
-    levels = [(f"{plan.format}:native", native, pallas_native)]
-    fb = spec.fallback if kind == "apply" else spec.fallback_permuted
-    if fb is not None:
-        levels.append((f"{plan.format}:unfused", fb, True))
-    levels.append(("reference", reference_apply(plan, kind), False))
-    return levels
+    pallas_native = get_format(plan.format).kernel != "xla"
+    return [(f"{plan.format}:native", native, pallas_native),
+            ("reference", reference_apply(plan, kind), False)]
+
+
+def _on_tpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -188,6 +192,8 @@ class _Guard:
                 chosen = (name, fn)
                 break
             except Exception as e:      # noqa: BLE001 — any lowering error
+                if _chaos_active() is None and _on_tpu():
+                    raise               # no silent degradation on the chip
                 bump("guard.level_failed")
                 failures.append((name, e))
         if chosen is None:

@@ -23,11 +23,10 @@ all slots through ONE decode (and one prefill) program, the concurrent
 users' head matvecs coalesce into a single batched SpMM apply of width
 ``batch`` — the head is planned at that width (``pruned_linear(..., k=)``)
 so format selection prices the amortized A-stream, and the batched apply
-routes to the SpMM megakernels that load each explicitly-cached x-tile once
-for the whole batch.  EHYB-family winners
-execute the fused megakernel pipeline inside ``SparseLinear.__call__``
-(permute in, ONE kernel launch with the ER rows folded into their owning
-partitions, un-permute out): activations arrive in feature order and logits
+routes to the SpMM apply that streams each A tile once for the whole
+batch.  EHYB-family winners run their permuted-space apply inside
+``SparseLinear.__call__`` (permute in, one apply with the ER rows folded
+into their owning partitions, un-permute out): activations arrive in feature order and logits
 must leave in vocab order every token, so the boundary gathers are inherent
 to serving — but everything between them is the same permuted-space fast
 path the solver loop runs on, and chained sparse layers can hoist the

@@ -3,11 +3,11 @@
 PRs 1–9 hardcoded three machine-sensitive constants deep inside the
 execution stack:
 
-* ``_GATHER_BUDGET`` (``kernels/ehyb_spmv.py``) — the VMEM byte budget that
-  sizes ``_w_chunk``'s gathered ``(V, Wc, R)`` intermediate, i.e. how deep
-  the static W sweep unrolls per partition;
-* ``_RHS_CHUNK`` (``kernels/ehyb_spmm.py``) — rhs columns per accumulator
-  chunk in the SpMM megakernels' K loop;
+* ``_GATHER_BUDGET`` (``kernels/ehyb_spmv.py``) — the VMEM bytes of packed
+  value/column tiles one grid step of the Pallas kernel streams in and
+  gathers against, i.e. how many partitions share a grid step;
+* ``_RHS_CHUNK`` (``kernels/ehyb_spmv.py``) — rhs columns per grid step of
+  a multi-rhs (SpMM) apply;
 * ``n_buckets`` (``core/ehyb.build_buckets``) — how many width classes the
   bucketed format splits its partition tiles into (more buckets = less
   padding, more kernel launches).
@@ -58,13 +58,13 @@ SEARCH_SPACE: Dict[str, ParamSpec] = {
         "gather_budget", default=4 * 1024 * 1024,
         candidates=(1 << 20, 2 << 20, 4 << 20, 8 << 20),
         lo=64 * 1024, hi=64 * 1024 * 1024,
-        description="VMEM bytes for the gathered (V, Wc, R) intermediate "
-                    "(sizes the Pallas kernels' static W-sweep chunk)"),
+        description="VMEM bytes of packed value/column tiles per grid "
+                    "step (sizes the partitions one Pallas step handles)"),
     "rhs_chunk": ParamSpec(
         "rhs_chunk", default=16, candidates=(8, 16, 32),
         lo=1, hi=256,
-        description="rhs columns per accumulator chunk in the SpMM "
-                    "megakernels' K loop"),
+        description="rhs columns per grid step of the Pallas SpMM "
+                    "apply"),
     "n_buckets": ParamSpec(
         "n_buckets", default=4, candidates=(2, 4, 8),
         lo=1, hi=16,
@@ -123,9 +123,9 @@ def sweep_grid(format: str, k: int = 1) -> Iterator[TunedParams]:
     Only the knobs a format actually reads are swept (the rest stay at
     their defaults, keeping the grid small and the plan identity honest):
 
-    * ``ehyb_packed`` — ``gather_budget`` (every Pallas kernel's W-sweep),
-      crossed with ``rhs_chunk`` when the plan's rhs width ``k`` routes to
-      the SpMM megakernels;
+    * ``ehyb_packed`` — ``gather_budget`` (partitions per Pallas grid
+      step), crossed with ``rhs_chunk`` when the plan's rhs width ``k`` is a
+      batch;
     * ``ehyb_bucketed`` — ``n_buckets`` (tile structure);
     * everything else — the defaults only (nothing to tune yet).
     """

@@ -294,13 +294,11 @@ def test_grad_fp64_cotangent_not_downcast(rng):
     the transpose bound at the promoted accumulation dtype the gradient
     agrees with the dense reference (built from the same fp32-rounded
     values, so storage rounding can't mask the bug) to fp64 resolution."""
-    from jax.experimental import enable_x64
-
     m = poisson3d(6)
     m32 = SparseCSR(m.n, m.indptr, m.indices,
                     m.data.astype(np.float32).astype(np.float64))
     dense = m32.to_dense()                          # fp64, fp32-rounded vals
-    with enable_x64():
+    with jax.enable_x64():
         op = api.plan(m).bind(m)                    # values stored at fp32
         x = jnp.asarray(rng.standard_normal(m.n), jnp.float64)
         v = jnp.asarray(rng.standard_normal(m.n), jnp.float64)

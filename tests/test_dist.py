@@ -335,7 +335,7 @@ def test_dist_equivalence_sweep():
             res[name + "/allgather_words"] = sop.plan.allgather_words
 
         # fp64 equivalence
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             m = poisson3d(10)
             mesh = make_mesh((4,), ("data",))
             sop = build_sharded_spmv(m, mesh, "data", format="ehyb",

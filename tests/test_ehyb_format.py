@@ -81,6 +81,10 @@ def test_bytes_model_orderings():
     f64 = e.bytes_moved(8)
     assert f64["total"] > f32["total"]
     assert e.bytes_moved(4)["total"] <= e.bytes_moved(4, col_bytes=4)["total"]
+    # the packed staircase stores whole (Sb, 128) tiles: it sits between the
+    # sliced and the uniform-tile layouts once a partition spans whole
+    # tiles (V = 256 = one (2, 128) tile here)
+    e = build_ehyb(m, n_parts=2, vec_size=256)
     sliced = e.bytes_moved(4, layout="sliced")["total"]
     tile = e.bytes_moved(4, layout="tile")["total"]
     packed = e.bytes_moved(4, layout="packed")["total"]

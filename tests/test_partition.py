@@ -5,6 +5,7 @@ import pytest
 
 from repro.core import (SUITE, choose_vec_size, make_partition, poisson3d,
                         unstructured)
+from repro.core.partition import MAX_LANE_ROWS, lane_geometry
 
 
 @pytest.mark.parametrize("method", ["natural", "bfs", "mincut", "hub"])
@@ -39,19 +40,27 @@ def test_bfs_beats_random_locality():
 
 
 def test_choose_vec_size_eq12():
-    """Paper Eq. 1–2: smallest K with dim·τ/(K·P) under the cache budget."""
+    """Paper Eq. 1–2: smallest K with dim·τ/(K·P) under the cache budget,
+    the x block counted lane-padded and its lane-rows capped by the
+    in-vreg gather."""
     n = 1_000_000
     n_parts, vec = choose_vec_size(n, dtype_bytes=4,
                                    vmem_budget_bytes=1 << 20, p_units=8)
-    assert vec * 4 < (1 << 20)
+    s, _ = lane_geometry(vec)
+    assert s * 128 * 4 < (1 << 20)      # lane-padded VMEM bytes
+    assert s <= MAX_LANE_ROWS           # gather span of the TPU kernel
     assert vec < (1 << 16)              # uint16 local indices (paper §3.4)
     assert vec % 8 == 0                 # sublane aligned
     assert n_parts % 8 == 0
-    # minimality: one fewer K would violate the budget
+    # minimality: one fewer K would violate a bound
     k = n_parts // 8
     if k > 1:
-        prev_vec = -(-n // ((k - 1) * 8))
-        assert prev_vec * 4 >= (1 << 20) or prev_vec >= (1 << 16)
+        prev_vec = -(-(-(-n // ((k - 1) * 8))) // 8) * 8
+        prev_s, _ = lane_geometry(prev_vec)
+        assert (prev_s * 128 * 4 >= (1 << 20) or prev_vec >= (1 << 16)
+                or prev_s > MAX_LANE_ROWS)
+    # HPCG's reference local grid (104³) lands on 552 partitions of 2040
+    assert choose_vec_size(104 ** 3) == (552, 2040)
 
 
 def test_natural_on_stencil_is_near_perfect():

@@ -180,11 +180,13 @@ def _fused_cases():
 @pytest.mark.parametrize("case", range(3))
 @pytest.mark.parametrize("dt,tol", [(jnp.float32, 1e-4), (jnp.bfloat16, 1e-1)])
 def test_fused_megakernel_vs_dense_oracle(case, dt, tol, rng):
+    from repro.core import EHYBPackedDevice, pack_staircase
     from repro.core.spmv import _to_permuted
-    from repro.kernels import ehyb_spmv_pallas, ehyb_spmv_pallas_permuted
+    from repro.kernels import (ehyb_spmv_packed_pallas,
+                               ehyb_spmv_packed_pallas_permuted)
 
     name, m, e = _fused_cases()[case]
-    dev = EHYBDevice.from_ehyb(e, dtype=dt)
+    dev = EHYBPackedDevice.from_packed(pack_staircase(e), dtype=dt)
     if name == "one_part":
         assert not dev.has_er              # everything cached, ER fully empty
     if name == "powerlaw":
@@ -195,11 +197,11 @@ def test_fused_megakernel_vs_dense_oracle(case, dt, tol, rng):
         y_ref = dense @ x
         scale = max(np.abs(y_ref).max(), 1.0)
         xj = jnp.asarray(x, dtype=dt)
-        y = np.asarray(ehyb_spmv_pallas(dev, xj), np.float64)
+        y = np.asarray(ehyb_spmv_packed_pallas(dev, xj), np.float64)
         assert np.abs(y - y_ref).max() / scale < tol, (name, shape)
-        # permuted-space entry: one pallas_call, no gathers
+        # permuted-space entry: one pallas_call + XLA ER, no gathers
         x_new, _ = _to_permuted(dev, xj)
-        y_new = ehyb_spmv_pallas_permuted(dev, x_new)
+        y_new = ehyb_spmv_packed_pallas_permuted(dev, x_new)
         y_p = np.asarray(y_new[np.asarray(dev.inv_perm)[: m.n]], np.float64)
         y_p = y_p if len(shape) > 1 else y_p[:, 0]
         assert np.abs(y_p - y_ref).max() / scale < tol, (name, shape)

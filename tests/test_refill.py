@@ -49,7 +49,7 @@ def _structure_work(before: dict, after: dict) -> dict:
 def test_refill_matches_fresh_build_bit_identical(fmt, dtype_name, gen):
     m1 = poisson3d(6) if gen == "stencil" else powerlaw(256, 4)
     m2 = _with_new_values(m1)
-    with jax.experimental.enable_x64(dtype_name == "float64"):
+    with jax.enable_x64(dtype_name == "float64"):
         dtype = jnp.dtype(dtype_name)
         op1 = build_spmv(m1, fmt, dtype)
         op2 = op1.update_values(m2)
@@ -227,7 +227,7 @@ def test_measured_solver_context_times_permuted_apply(monkeypatch):
 
 def test_diag_precond_closure_preserves_fp64():
     inv = np.full(16, 0.5)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         r64 = jnp.ones(16, jnp.float64)
         assert _diag_closure(inv)(r64).dtype == jnp.float64
     r32 = jnp.ones(16, jnp.float32)
@@ -236,7 +236,7 @@ def test_diag_precond_closure_preserves_fp64():
 
 def test_fp64_solve_stays_fp64_end_to_end():
     m = poisson3d(6)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         b = jnp.asarray(np.random.default_rng(3).standard_normal(m.n),
                         jnp.float64)
         r = solve(m, b, precond="jacobi", format="csr", tol=1e-12,

@@ -10,7 +10,7 @@ import pytest
 
 from repro import api
 from repro.autotune import available_formats, estimate_bytes
-from repro.core import EHYBDevice, build_ehyb, poisson3d, powerlaw
+from repro.core import build_ehyb, poisson3d, powerlaw
 from repro.core.matrices import SparseCSR
 
 
@@ -42,17 +42,19 @@ def test_spmm_conformance_all_formats(kind, k, dtype, tol, rng):
         assert err < tol, (fmt, kind, k, err)
 
 
-@pytest.mark.parametrize("use_er_kernel", [True, False])
-def test_spmm_megakernel_matches_oracle(use_er_kernel, rng):
-    """The Pallas SpMM megakernels themselves (fused ELL+ER and ELL-only +
-    jnp ER fallback), at a k that exercises the rhs-chunk remainder."""
-    from repro.kernels import ehyb_spmv_pallas
+@pytest.mark.parametrize("rhs_chunk", [16, 2])
+def test_spmm_megakernel_matches_oracle(rhs_chunk, rng):
+    """The Pallas SpMM apply (packed kernel over all rhs + XLA ER), at a k
+    that exercises the rhs-chunk remainder when the chunk is 2."""
+    from repro.core import EHYBPackedDevice, pack_staircase
+    from repro.kernels import ehyb_spmv_packed_pallas
 
     m = powerlaw(192, 6)
-    dev = EHYBDevice.from_ehyb(build_ehyb(m))
+    dev = EHYBPackedDevice.from_packed(
+        pack_staircase(build_ehyb(m)), kparams=(("rhs_chunk", rhs_chunk),))
     X = jnp.asarray(rng.standard_normal((m.n, 5)), jnp.float32)
-    Y = np.asarray(ehyb_spmv_pallas(dev, X, interpret=True,
-                                    use_er_kernel=use_er_kernel), np.float64)
+    Y = np.asarray(ehyb_spmv_packed_pallas(dev, X, interpret=True),
+                   np.float64)
     ref = m.to_dense() @ np.asarray(X, np.float64)
     scale = np.abs(ref).max() + 1e-30
     assert np.abs(Y - ref).max() / scale < 5e-5

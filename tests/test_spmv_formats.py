@@ -38,7 +38,7 @@ def test_all_formats_fp32(mat, rng):
 
 
 def test_ehyb_fp64(rng):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         m = poisson3d(6)
         e = build_ehyb(m)
         dev = EHYBDevice.from_ehyb(e, dtype=jnp.float64)
