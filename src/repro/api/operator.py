@@ -31,10 +31,13 @@ Space.PERMUTED)`` / ``op.from_space(ỹ)`` — the explicit form of the old
 from __future__ import annotations
 
 import dataclasses
+import functools
+import itertools
 from typing import Any, Optional
 
 import numpy as np
 
+from ..core.counters import span
 from ..core.matrices import SparseCSR
 from .config import Space
 from .plan import Plan
@@ -465,6 +468,20 @@ def _better(r_old, r_new):
     return r_new if res_new <= res_old else r_old
 
 
+_SOLVE_IDS = itertools.count(1)
+
+
+def _solve_span(fn):
+    """``fn`` run inside a ``repro.solve`` span that carries a running
+    solve id into the trace; the solve's stage spans nest inside it."""
+    @functools.wraps(fn)
+    def spanned(*args, **kwargs):
+        with span("repro.solve", id=next(_SOLVE_IDS)):
+            return fn(*args, **kwargs)
+    return spanned
+
+
+@_solve_span
 def solve_operator(op, b, *, method: str = "cg", precond: str = "jacobi",
                    x0=None, tol: float = 1e-6, max_iters: int = 500,
                    space="auto", fused_update="auto", policy=None,
@@ -526,17 +543,19 @@ def solve_operator(op, b, *, method: str = "cg", precond: str = "jacobi",
     a = op.csr
     from ..autotune.cost import matrix_key
 
-    key = matrix_key(a)
-    b = jnp.asarray(b)
-    if use_perm:
-        pre, inv = S._cached_precond(a, precond, key,
-                                     perm=np.asarray(op.obj.perm),
-                                     n_pad=op.n_pad)
-        b_run = op.to_space(b, Space.PERMUTED)
-        mv = op.matvec_permuted
-    else:
-        pre, inv = S._cached_precond(a, precond, key)
-        b_run, mv = b, op.matvec
+    with span("repro.solve.key"):
+        key = matrix_key(a)
+    with span("repro.solve.precond"):
+        if use_perm:
+            pre, inv = S._cached_precond(a, precond, key,
+                                         perm=np.asarray(op.obj.perm),
+                                         n_pad=op.n_pad)
+        else:
+            pre, inv = S._cached_precond(a, precond, key)
+    with span("repro.solve.to_space"):
+        b = jnp.asarray(b)
+        b_run = op.to_space(b, Space.PERMUTED) if use_perm else b
+    mv = op.matvec_permuted if use_perm else op.matvec
     kw_guard = {}
     if policy is not None:
         kw_guard = {"stag_window": policy.stagnation_window,
@@ -546,8 +565,10 @@ def solve_operator(op, b, *, method: str = "cg", precond: str = "jacobi",
     def _run_local(method_, x0_orig):
         x0_run = None
         if x0_orig is not None:
-            x0a = jnp.asarray(x0_orig, b.dtype)
-            x0_run = op.to_space(x0a, Space.PERMUTED) if use_perm else x0a
+            with span("repro.solve.to_space"):
+                x0a = jnp.asarray(x0_orig, b.dtype)
+                x0_run = (op.to_space(x0a, Space.PERMUTED) if use_perm
+                          else x0a)
         kw = dict(kw_guard)
         if method_ == "cg":
             kw.update(fused_update=bool(fused_update),
@@ -556,13 +577,15 @@ def solve_operator(op, b, *, method: str = "cg", precond: str = "jacobi",
                                                               jnp.float32)))
         elif policy is not None and policy.breakdown_tol is not None:
             kw["breakdown_tol"] = policy.breakdown_tol
-        r = S.SOLVERS[method_](mv, b_run, pre, tol=tol,
-                               max_iters=max_iters, x0=x0_run, **kw)
+        with span("repro.solve.loop"):
+            r = S.SOLVERS[method_](mv, b_run, pre, tol=tol,
+                                   max_iters=max_iters, x0=x0_run, **kw)
         if use_perm:
-            r = S.SolveResult(x=op.from_space(r.x, Space.PERMUTED),
-                              iters=r.iters, residual=r.residual,
-                              converged=r.converged,
-                              status_code=r.status_code)
+            with span("repro.solve.from_space"):
+                r = S.SolveResult(x=op.from_space(r.x, Space.PERMUTED),
+                                  iters=r.iters, residual=r.residual,
+                                  converged=r.converged,
+                                  status_code=r.status_code)
         return r
 
     r = _run_local(method, x0)
@@ -610,7 +633,9 @@ def solve_operator(op, b, *, method: str = "cg", precond: str = "jacobi",
 
 
 def _finalize_solve(r, stages, raise_on_failure, warn):
-    """Terminal accounting: a non-converged result is never silent."""
+    """Terminal accounting: a non-converged result is never silent.  The
+    ``repro.solve.finalize`` span times ``bool(r.converged)``, which waits
+    for the device."""
     import jax
 
     if isinstance(r.converged, jax.core.Tracer):
@@ -618,7 +643,9 @@ def _finalize_solve(r, stages, raise_on_failure, warn):
     from ..core.counters import bump as _bump
     from ..reliability.policy import SolveFailure, SolveFailureWarning
 
-    if bool(r.converged):
+    with span("repro.solve.finalize"):
+        converged = bool(r.converged)
+    if converged:
         if stages:
             _bump("solver.recovered")
         return r

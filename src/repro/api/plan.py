@@ -36,6 +36,7 @@ from typing import Any, List, Optional, Tuple
 import numpy as np
 
 from ..core.cache import BoundedCache
+from ..core.counters import carry, span
 from ..core.matrices import SparseCSR
 from .config import ExecutionConfig
 
@@ -67,7 +68,7 @@ def _run_untraced(fn):
 
         _UNTRACED_POOL = concurrent.futures.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="repro-plan")
-    return _UNTRACED_POOL.submit(fn).result()
+    return _UNTRACED_POOL.submit(carry(fn)).result()
 
 
 _UNTRACED_POOL = None
@@ -121,7 +122,8 @@ class PlanCache:
         ck = (key, execution.token(), None if mesh is None else (mesh, axis))
         p = self._plans.get(ck)
         if p is None:
-            p = Plan._create(pattern, key, mesh, axis, execution, self)
+            with span("repro.plan"):
+                p = Plan._create(pattern, key, mesh, axis, execution, self)
             self._plans[ck] = p
         return p
 
@@ -341,8 +343,10 @@ class Plan:
         # candidate set (or mesh shardability) rules out is ignored.
         from ..tuning.params import resolve as _resolve_params
 
-        entry, part_loaded = cache.load(key, context, dtype=execution.dtype,
-                                        k=execution.k, n_dev=n_dev)
+        with span("repro.plan.store"):
+            entry, part_loaded = cache.load(key, context,
+                                            dtype=execution.dtype,
+                                            k=execution.k, n_dev=n_dev)
         if entry is not None:
             allowed = execution.candidates or at.available_formats()
             if fmt == "auto" and (entry.format not in allowed or (
@@ -371,16 +375,18 @@ class Plan:
                 import jax.numpy as jnp
 
                 kw = {"n_dev": n_dev} if context == "dist" else {}
-                ptuning = at.autotune_partition(
-                    pattern, context=context,
-                    val_bytes=jnp.dtype(execution.dtype
-                                        or jnp.float32).itemsize, **kw)
+                with span("repro.plan.price"):
+                    ptuning = at.autotune_partition(
+                        pattern, context=context,
+                        val_bytes=jnp.dtype(execution.dtype
+                                            or jnp.float32).itemsize, **kw)
                 method = ptuning.strategy
         if method is not None:
             part_seed = (ptuning.partition if ptuning is not None
                          else part_loaded)
-            shared["ehyb"] = cache.host_ehyb(pattern, method=method,
-                                             part=part_seed)
+            with span("repro.plan.build"):
+                shared["ehyb"] = cache.host_ehyb(pattern, method=method,
+                                                 part=part_seed)
         # ---- tuned kernel parameters + format ------------------------------
         tuned = execution.tuned
         if tuned is None and entry is not None:
@@ -396,10 +402,11 @@ class Plan:
             if mesh is not None:
                 cand = tuple(f for f in (cand or shardable) if f in shardable)
             kw = {"n_dev": n_dev} if context == "dist" else {}
-            tuning = at.autotune(pattern, execution.dtype,
-                                 mode=execution.mode, candidates=cand,
-                                 shared=shared, context=context,
-                                 k=execution.k, tuned=tuned, **kw)
+            with span("repro.plan.autotune"):
+                tuning = at.autotune(pattern, execution.dtype,
+                                     mode=execution.mode, candidates=cand,
+                                     shared=shared, context=context,
+                                     k=execution.k, tuned=tuned, **kw)
             fmt = tuning.format
             if tuned is None and tuning.tuned is not None:
                 from ..tuning.params import TunedParams
@@ -415,7 +422,8 @@ class Plan:
                 partition_tuning=ptuning, tuned=tuned, pattern=pattern,
                 cache=cache, _shared=shared)
         if entry is None:
-            cache.save(p)        # no-op without an active store
+            with span("repro.plan.store"):
+                cache.save(p)    # no-op without an active store
         return p
 
     # ---- binding -----------------------------------------------------------
@@ -495,24 +503,25 @@ class Plan:
         if _is_traced(values) or (not isinstance(values, SparseCSR)
                                   and _is_traced(jnp.asarray(values))):
             return self._bind_traced(values, dtype)
-        csr, data = self._as_csr(values)
-        if validate:
-            self._validate_bind(data)
-        tpl = self._template_for(dtype, csr)
-        op = LinearOperator(plan=self, obj=tpl.obj)
-        op._dtype = jnp.dtype(dtype)
-        op._csr = csr
-        op._values = data
-        if validate == "full":
-            from ..analysis import errors, verify
+        with span("repro.bind"):
+            csr, data = self._as_csr(values)
+            if validate:
+                self._validate_bind(data)
+            tpl = self._template_for(dtype, csr)
+            op = LinearOperator(plan=self, obj=tpl.obj)
+            op._dtype = jnp.dtype(dtype)
+            op._csr = csr
+            op._values = data
+            if validate == "full":
+                from ..analysis import errors, verify
 
-            bad = errors(verify(op))
-            if bad:
-                detail = "; ".join(str(f) for f in bad[:4])
-                raise ValueError(
-                    f"bind(validate='full'): {len(bad)} invariant "
-                    f"violation(s) in the bound {self.format!r} container: "
-                    f"{detail}")
+                bad = errors(verify(op))
+                if bad:
+                    detail = "; ".join(str(f) for f in bad[:4])
+                    raise ValueError(
+                        f"bind(validate='full'): {len(bad)} invariant "
+                        f"violation(s) in the bound {self.format!r} "
+                        f"container: {detail}")
         return op
 
     def _template_for(self, dtype, csr: Optional[SparseCSR] = None):
@@ -524,7 +533,8 @@ class Plan:
 
         dt_name = jnp.dtype(dtype).name
         seed = csr if csr is not None else self.pattern
-        mk = matrix_key(seed, self.key)
+        with span("repro.bind.key"):
+            mk = matrix_key(seed, self.key)
         slot = self._templates.get(dt_name)
         if slot is None:
             tpl = self._build_template(seed, dtype)

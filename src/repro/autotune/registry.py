@@ -44,6 +44,7 @@ from typing import Any, Callable, Dict, Optional
 
 import numpy as np
 
+from ..core.counters import span
 from ..core.ehyb import (EHYB, build_buckets, build_ehyb,
                          group_er_by_partition, pack_staircase)
 from ..core.matrices import SparseCSR
@@ -212,10 +213,13 @@ def _build_ehyb_bucketed(m, dtype, shared):
 def _build_ehyb_packed(m, dtype, shared):
     from ..kernels.ops import ehyb_spmv_packed_pallas
 
-    pk = shared_packed(m, shared)
+    with span("repro.bind.pack"):
+        pk = shared_packed(m, shared)
+        group_er_by_partition(pk.base)   # memoized; the upload reads it
     tuned = shared.get("tuned")
-    obj = EHYBPackedDevice.from_packed(
-        pk, dtype, kparams=tuned.token() if tuned is not None else ())
+    with span("repro.bind.upload"):
+        obj = EHYBPackedDevice.from_packed(
+            pk, dtype, kparams=tuned.token() if tuned is not None else ())
     obj.host_packed = pk              # refill provenance (not pytree state)
     return obj, ehyb_spmv_packed_pallas
 

@@ -25,12 +25,11 @@ padding bytes.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import numpy as np
 
-from .counters import bump
+from .counters import bump, span
 from .matrices import SparseCSR
 from .partition import LANES, Partition, lane_geometry, make_partition
 
@@ -219,45 +218,45 @@ class EHYB:
             raise ValueError(f"value buffer has {new_data.shape} entries; "
                              f"pattern holds {self.nnz}")
         bump("ehyb_refill")
-        t0 = time.perf_counter()
-        plan = self.fill_plan
-        ell = np.zeros(self.n_pad * self.ell_width, dtype=np.float64)
-        ell[plan["ell_dst"]] = new_data[plan["ell_src"]]
-        ell = ell.reshape(self.n_parts, self.vec_size, self.ell_width)
-        er = np.zeros(self.er_rows * self.er_width, dtype=np.float64)
-        er[plan["er_dst"]] = new_data[plan["er_src"]]
-        er = er.reshape(self.er_rows, self.er_width)
-        new = dataclasses.replace(self, ell_vals=ell, er_vals=er,
-                                  preprocess_seconds={})
-        g = getattr(self, "_er_grouped", None)
-        if g is not None:
-            gp = np.zeros_like(g["er_p_vals"])
-            gp[g["own"], g["slot"]] = er[g["src"]]
-            new._er_grouped = {**g, "er_p_vals": gp}
-        def _refill_buckets(b):
-            return EHYBBuckets(
-                base=new, part_ids=b.part_ids,
-                vals=[np.ascontiguousarray(ell[ch, :, : v.shape[2]])
-                      for ch, v in zip(b.part_ids, b.vals)],
-                cols=b.cols, widths=b.widths)
+        with span("repro.ehyb.refill") as refill:
+            plan = self.fill_plan
+            ell = np.zeros(self.n_pad * self.ell_width, dtype=np.float64)
+            ell[plan["ell_dst"]] = new_data[plan["ell_src"]]
+            ell = ell.reshape(self.n_parts, self.vec_size, self.ell_width)
+            er = np.zeros(self.er_rows * self.er_width, dtype=np.float64)
+            er[plan["er_dst"]] = new_data[plan["er_src"]]
+            er = er.reshape(self.er_rows, self.er_width)
+            new = dataclasses.replace(self, ell_vals=ell, er_vals=er,
+                                      preprocess_seconds={})
+            g = getattr(self, "_er_grouped", None)
+            if g is not None:
+                gp = np.zeros_like(g["er_p_vals"])
+                gp[g["own"], g["slot"]] = er[g["src"]]
+                new._er_grouped = {**g, "er_p_vals": gp}
+            def _refill_buckets(b):
+                return EHYBBuckets(
+                    base=new, part_ids=b.part_ids,
+                    vals=[np.ascontiguousarray(ell[ch, :, : v.shape[2]])
+                          for ch, v in zip(b.part_ids, b.vals)],
+                    cols=b.cols, widths=b.widths)
 
-        b = getattr(self, "_buckets", None)
-        if b is not None:
-            new._buckets = _refill_buckets(b)
-        # non-default bucket counts (tuned n_buckets) memoize separately —
-        # refill them through the same value-only path so a tuned bucketed
-        # operator never silently re-buckets
-        nb = getattr(self, "_buckets_nb", None)
-        if nb is not None:
-            new._buckets_nb = {count: _refill_buckets(bb)
-                               for count, bb in nb.items()}
-        pk = getattr(self, "_packed", None)
-        if pk is not None:
-            new._packed = pk.refill(new)
-        dt = time.perf_counter() - t0
+            b = getattr(self, "_buckets", None)
+            if b is not None:
+                new._buckets = _refill_buckets(b)
+            # non-default bucket counts (tuned n_buckets) memoize apart —
+            # refill them through the same value-only path so a tuned bucketed
+            # operator never silently re-buckets
+            nb = getattr(self, "_buckets_nb", None)
+            if nb is not None:
+                new._buckets_nb = {count: _refill_buckets(bb)
+                                   for count, bb in nb.items()}
+            pk = getattr(self, "_packed", None)
+            if pk is not None:
+                new._packed = pk.refill(new)
         # structure passes cost exactly zero on a refill — that IS the point
         new.preprocess_seconds = {"partition": 0.0, "metadata": 0.0,
-                                  "reorder": 0.0, "refill": dt, "total": dt}
+                                  "reorder": 0.0, "refill": refill.seconds,
+                                  "total": refill.seconds}
         return new
 
 
@@ -272,116 +271,115 @@ def build_ehyb(m: SparseCSR, part: Optional[Partition] = None,
     for power-law matrices.
     """
     bump("build_ehyb")
-    t0 = time.perf_counter()
     if part is None:
         part = make_partition(m, method=method, dtype_bytes=dtype_bytes,
                               **part_kw)
     # a prebuilt `part` (e.g. the autotuned winner) carries its own timing
-    t_part = max(time.perf_counter() - t0, getattr(part, "seconds", 0.0))
+    t_part = getattr(part, "seconds", 0.0)
 
-    t0 = time.perf_counter()
-    n, n_parts, V = m.n, part.n_parts, part.vec_size
-    n_pad = part.n_pad
-    rows = np.repeat(np.arange(n, dtype=np.int64), m.row_lengths())
-    cols = m.indices.astype(np.int64)
-    vals = m.data
-    same = part.part_vec[rows] == part.part_vec[cols]
+    with span("repro.ehyb.metadata") as meta:
+        n, n_parts, V = m.n, part.n_parts, part.vec_size
+        n_pad = part.n_pad
+        rows = np.repeat(np.arange(n, dtype=np.int64), m.row_lengths())
+        cols = m.indices.astype(np.int64)
+        vals = m.data
+        same = part.part_vec[rows] == part.part_vec[cols]
 
-    # ---- per-row in-partition counts drive the within-partition sort
-    # (Algo 1 lines 3–18) --------------------------------------------------
-    in_counts = np.bincount(rows[same], minlength=n)
-    # current slots from the partition (grouped by partition, orig order)
-    base_slot = part.inv_perm[:n]
-    part_of = base_slot // V
-    # sort within each partition by (-in_count, orig index) — stable & exact
-    order = np.lexsort((np.arange(n), -in_counts, part_of))
-    # `order` lists vertices partition-major; rebuild slots with row-sort
-    slot_rank = np.empty(n, dtype=np.int64)
-    counts_per_part = np.bincount(part_of, minlength=n_parts)
-    starts = np.concatenate([[0], np.cumsum(counts_per_part)])
-    slot_rank[order] = np.arange(n) - starts[part_of[order]]
-    inv_perm = np.full(n_pad, -1, dtype=np.int64)
-    inv_perm[:n] = part_of * V + slot_rank
-    # padding vertices fill remaining slots of each partition
-    all_slots = np.zeros(n_pad, dtype=bool)
-    all_slots[inv_perm[:n]] = True
-    free_slots = np.flatnonzero(~all_slots)
-    inv_perm[n:] = free_slots
-    perm = np.empty(n_pad, dtype=np.int64)
-    perm[inv_perm] = np.arange(n_pad)
+        # ---- per-row in-partition counts drive the within-partition sort
+        # (Algo 1 lines 3–18) ----------------------------------------------
+        in_counts = np.bincount(rows[same], minlength=n)
+        # current slots from the partition (grouped by partition, orig order)
+        base_slot = part.inv_perm[:n]
+        part_of = base_slot // V
+        # sort within each partition by (-in_count, orig index): stable,
+        # exact
+        order = np.lexsort((np.arange(n), -in_counts, part_of))
+        # `order` lists vertices partition-major; rebuild slots with row-sort
+        slot_rank = np.empty(n, dtype=np.int64)
+        counts_per_part = np.bincount(part_of, minlength=n_parts)
+        starts = np.concatenate([[0], np.cumsum(counts_per_part)])
+        slot_rank[order] = np.arange(n) - starts[part_of[order]]
+        inv_perm = np.full(n_pad, -1, dtype=np.int64)
+        inv_perm[:n] = part_of * V + slot_rank
+        # padding vertices fill remaining slots of each partition
+        all_slots = np.zeros(n_pad, dtype=bool)
+        all_slots[inv_perm[:n]] = True
+        free_slots = np.flatnonzero(~all_slots)
+        inv_perm[n:] = free_slots
+        perm = np.empty(n_pad, dtype=np.int64)
+        perm[inv_perm] = np.arange(n_pad)
 
-    new_r = inv_perm[rows]
-    new_c = inv_perm[cols]
+        new_r = inv_perm[rows]
+        new_c = inv_perm[cols]
 
-    # ---- split in-partition / ER, with optional width cap -----------------
-    in_mask = same.copy()
-    if max_width is not None:
-        # spill entries beyond max_width per row (keep smallest local cols)
-        ord_in = np.lexsort((new_c, new_r))
-        rr = new_r[ord_in][same[ord_in]]
-        # rank of each in-part entry within its row
-        idx_in = ord_in[same[ord_in]]
-        row_change = np.concatenate([[True], rr[1:] != rr[:-1]])
-        grp_start = np.maximum.accumulate(np.where(row_change,
-                                                   np.arange(len(rr)), 0))
-        rank = np.arange(len(rr)) - grp_start
-        spill = idx_in[rank >= max_width]
-        in_mask[spill] = False
+        # ---- split in-partition / ER, with optional width cap -------------
+        in_mask = same.copy()
+        if max_width is not None:
+            # spill entries beyond max_width per row (keep smallest local cols)
+            ord_in = np.lexsort((new_c, new_r))
+            rr = new_r[ord_in][same[ord_in]]
+            # rank of each in-part entry within its row
+            idx_in = ord_in[same[ord_in]]
+            row_change = np.concatenate([[True], rr[1:] != rr[:-1]])
+            grp_start = np.maximum.accumulate(np.where(row_change,
+                                                       np.arange(len(rr)), 0))
+            rank = np.arange(len(rr)) - grp_start
+            spill = idx_in[rank >= max_width]
+            in_mask[spill] = False
 
-    t_reorder0 = time.perf_counter()
+    with span("repro.ehyb.reorder") as reorder:
+        # ---- fill sliced-ELL (Algo 2, lines 4–8) --------------------------
+        sel = np.flatnonzero(in_mask)
+        order_in = sel[np.lexsort((new_c[sel], new_r[sel]))]
+        r_in = new_r[order_in]
+        widths = np.bincount(r_in, minlength=n_pad)
+        W = int(widths.max()) if len(r_in) else 1
+        W = max(W, 1)
+        part_widths = widths.reshape(n_parts, V).max(axis=1).astype(np.int32)
+        row_start = np.concatenate([[0], np.cumsum(widths)])
+        k = np.arange(len(r_in)) - row_start[r_in]
+        ell_vals = np.zeros((n_pad, W), dtype=np.float64)
+        ell_cols = np.zeros((n_pad, W), dtype=np.uint16)
+        ell_vals[r_in, k] = vals[order_in]
+        local = (new_c[order_in] - (r_in // V) * V)
+        if V > (1 << 16):
+            raise ValueError("vec_size exceeds uint16 local index range")
+        ell_cols[r_in, k] = local.astype(np.uint16)
+        ell_vals = ell_vals.reshape(n_parts, V, W)
+        ell_cols = ell_cols.reshape(n_parts, V, W)
+        # per 8-row-slice widths (paper's sliced-ELL accounting granularity)
+        slice_widths = widths.reshape(n_parts, V // sublane, sublane).max(
+            axis=2).astype(np.int32) if V % sublane == 0 else None
 
-    # ---- fill sliced-ELL (Algo 2, lines 4–8) ------------------------------
-    sel = np.flatnonzero(in_mask)
-    order_in = sel[np.lexsort((new_c[sel], new_r[sel]))]
-    r_in = new_r[order_in]
-    widths = np.bincount(r_in, minlength=n_pad)
-    W = int(widths.max()) if len(r_in) else 1
-    W = max(W, 1)
-    part_widths = widths.reshape(n_parts, V).max(axis=1).astype(np.int32)
-    row_start = np.concatenate([[0], np.cumsum(widths)])
-    k = np.arange(len(r_in)) - row_start[r_in]
-    ell_vals = np.zeros((n_pad, W), dtype=np.float64)
-    ell_cols = np.zeros((n_pad, W), dtype=np.uint16)
-    ell_vals[r_in, k] = vals[order_in]
-    local = (new_c[order_in] - (r_in // V) * V)
-    if V > (1 << 16):
-        raise ValueError("vec_size exceeds uint16 local index range")
-    ell_cols[r_in, k] = local.astype(np.uint16)
-    ell_vals = ell_vals.reshape(n_parts, V, W)
-    ell_cols = ell_cols.reshape(n_parts, V, W)
-    # per 8-row-slice widths (paper's sliced-ELL accounting granularity)
-    slice_widths = widths.reshape(n_parts, V // sublane, sublane).max(
-        axis=2).astype(np.int32) if V % sublane == 0 else None
-
-    # ---- fill ER (Algo 2, lines 10–13; Algo 1 lines 16, 23–26) ------------
-    sel_er = np.flatnonzero(~in_mask)
-    er_counts = np.bincount(new_r[sel_er], minlength=n_pad)
-    er_rows_idx = np.flatnonzero(er_counts)
-    # global sort by descending out-count (Algo 1 line 16)
-    er_rows_idx = er_rows_idx[np.argsort(-er_counts[er_rows_idx],
-                                         kind="stable")]
-    n_er = len(er_rows_idx)
-    n_er_pad = max(sublane, -(-max(n_er, 1) // sublane) * sublane)
-    er_width = int(er_counts.max()) if n_er else 1
-    er_vals = np.zeros((n_er_pad, er_width), dtype=np.float64)
-    er_cols = np.zeros((n_er_pad, er_width), dtype=np.int32)
-    er_row_idx = np.zeros(n_er_pad, dtype=np.int32)
-    er_dst = np.empty(0, dtype=np.int64)
-    er_src = np.empty(0, dtype=np.int64)
-    if n_er:
-        er_row_idx[:n_er] = er_rows_idx
-        er_slot = np.full(n_pad, -1, dtype=np.int64)
-        er_slot[er_rows_idx] = np.arange(n_er)
-        order_er = sel_er[np.lexsort((new_c[sel_er], new_r[sel_er]))]
-        r_er = new_r[order_er]
-        rs = np.concatenate([[0], np.cumsum(np.bincount(r_er, minlength=n_pad))])
-        kk = np.arange(len(r_er)) - rs[r_er]
-        er_vals[er_slot[r_er], kk] = vals[order_er]
-        er_cols[er_slot[r_er], kk] = new_c[order_er].astype(np.int32)
-        er_dst = er_slot[r_er] * er_width + kk
-        er_src = order_er
-    t_reorder = time.perf_counter() - t_reorder0
-    t_meta = t_reorder0 - t0
+        # ---- fill ER (Algo 2, lines 10–13; Algo 1 lines 16, 23–26) --------
+        sel_er = np.flatnonzero(~in_mask)
+        er_counts = np.bincount(new_r[sel_er], minlength=n_pad)
+        er_rows_idx = np.flatnonzero(er_counts)
+        # global sort by descending out-count (Algo 1 line 16)
+        er_rows_idx = er_rows_idx[np.argsort(-er_counts[er_rows_idx],
+                                             kind="stable")]
+        n_er = len(er_rows_idx)
+        n_er_pad = max(sublane, -(-max(n_er, 1) // sublane) * sublane)
+        er_width = int(er_counts.max()) if n_er else 1
+        er_vals = np.zeros((n_er_pad, er_width), dtype=np.float64)
+        er_cols = np.zeros((n_er_pad, er_width), dtype=np.int32)
+        er_row_idx = np.zeros(n_er_pad, dtype=np.int32)
+        er_dst = np.empty(0, dtype=np.int64)
+        er_src = np.empty(0, dtype=np.int64)
+        if n_er:
+            er_row_idx[:n_er] = er_rows_idx
+            er_slot = np.full(n_pad, -1, dtype=np.int64)
+            er_slot[er_rows_idx] = np.arange(n_er)
+            order_er = sel_er[np.lexsort((new_c[sel_er], new_r[sel_er]))]
+            r_er = new_r[order_er]
+            rs = np.concatenate(
+                [[0], np.cumsum(np.bincount(r_er, minlength=n_pad))])
+            kk = np.arange(len(r_er)) - rs[r_er]
+            er_vals[er_slot[r_er], kk] = vals[order_er]
+            er_cols[er_slot[r_er], kk] = new_c[order_er].astype(np.int32)
+            er_dst = er_slot[r_er] * er_width + kk
+            er_src = order_er
+    t_meta, t_reorder = meta.seconds, reorder.seconds
 
     # value-refresh plan: the two scatters above, recorded as flat indices
     # (``refill`` replays them on a new value buffer with zero structure work)
@@ -543,26 +541,28 @@ def pack_staircase(e: EHYB) -> PackedEHYB:
     ``v < col_rows[p, k]`` (rows are width-sorted, so column k's active rows
     are the prefix [0, R_k)), and its destination within partition p's
     flat packed stream is ``col_starts[p, k]·(Sb·128) + v``.  The scatter
-    is recorded in ``preprocess_seconds["pack"]``.
+    is timed by the ``repro.ehyb.pack`` span, recorded in
+    ``preprocess_seconds["pack"]``.
     """
     bump("pack_staircase")
-    t0 = time.perf_counter()
-    p_, v_, w_ = e.n_parts, e.vec_size, e.ell_width
-    col_rows = staircase_rows(e)
-    _, sb = lane_geometry(v_)
-    tile = sb * LANES
-    col_starts = np.zeros((p_, w_ + 1), dtype=np.int32)
-    col_starts[:, 1:] = np.cumsum(-(-col_rows // tile), axis=1)
-    n_tiles = max(int(col_starts[:, -1].max()), 1)
-    pack_l = n_tiles * tile
-    packed_vals = np.zeros((p_, pack_l), dtype=e.ell_vals.dtype)
-    packed_cols = np.zeros((p_, pack_l), dtype=np.uint16)
-    active = np.arange(v_)[None, :, None] < col_rows[:, None, :]  # (P, V, W)
-    pi, vi, ki = np.nonzero(active)
-    dest = col_starts[pi, ki].astype(np.int64) * tile + vi
-    packed_vals[pi, dest] = e.ell_vals[pi, vi, ki]
-    packed_cols[pi, dest] = e.ell_cols[pi, vi, ki]
-    e.preprocess_seconds["pack"] = time.perf_counter() - t0
+    with span("repro.ehyb.pack") as pack:
+        p_, v_, w_ = e.n_parts, e.vec_size, e.ell_width
+        col_rows = staircase_rows(e)
+        _, sb = lane_geometry(v_)
+        tile = sb * LANES
+        col_starts = np.zeros((p_, w_ + 1), dtype=np.int32)
+        col_starts[:, 1:] = np.cumsum(-(-col_rows // tile), axis=1)
+        n_tiles = max(int(col_starts[:, -1].max()), 1)
+        pack_l = n_tiles * tile
+        packed_vals = np.zeros((p_, pack_l), dtype=e.ell_vals.dtype)
+        packed_cols = np.zeros((p_, pack_l), dtype=np.uint16)
+        # (P, V, W)
+        active = np.arange(v_)[None, :, None] < col_rows[:, None, :]
+        pi, vi, ki = np.nonzero(active)
+        dest = col_starts[pi, ki].astype(np.int64) * tile + vi
+        packed_vals[pi, dest] = e.ell_vals[pi, vi, ki]
+        packed_cols[pi, dest] = e.ell_cols[pi, vi, ki]
+    e.preprocess_seconds["pack"] = pack.seconds
     shape = (p_, n_tiles, sb, LANES)
     return PackedEHYB(base=e, packed_len=pack_l,
                       packed_vals=packed_vals.reshape(shape),

@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
-import time
 from typing import Callable, Dict
 
 import numpy as np
@@ -569,18 +568,18 @@ def make_partition(m: SparseCSR, method: str = "bfs",
 
     Strategy kwargs are validated against the strategy's signature: an
     unknown keyword raises ``TypeError`` for *every* strategy (``natural``
-    included), never a silent drop.  Wall-clock time lands in
-    ``Partition.seconds`` (and from there in the EHYB builder's
+    included), never a silent drop.  The ``repro.partition`` span's time
+    lands in ``Partition.seconds`` (and from there in the EHYB builder's
     ``preprocess_seconds["partition"]``).
     """
-    from .counters import bump
+    from .counters import bump, span
 
     bump("partition")
     if n_parts is None or vec_size is None:
         n_parts, vec_size = choose_vec_size(m.n, dtype_bytes)
-    t0 = time.perf_counter()
-    p = _invoke(method, m, n_parts, vec_size, **kw)
-    p.seconds = time.perf_counter() - t0
+    with span("repro.partition") as timed:
+        p = _invoke(method, m, n_parts, vec_size, **kw)
+    p.seconds = timed.seconds
     return p
 
 

@@ -285,18 +285,25 @@ def _ehyb_ell_part(ell_vals, ell_cols, x_parts):
     return jax.vmap(one_part)(x_parts, ell_cols, ell_vals)   # (P, V, R)
 
 
+# Device scopes: every op traced below carries its stage in its HLO
+# ``op_name`` (``repro.permute``, ``repro.er`` > ``repro.er.gather`` /
+# ``repro.er.scatter``), so a profiler trace can split the apply's XLA
+# side by stage.  A name scope costs nothing at run time.
+
 def _to_permuted(obj, x: jnp.ndarray) -> tuple[jnp.ndarray, bool]:
     """Original (n[,R]) vector(s) -> permuted padded (n_pad[,R]) space."""
-    x2, squeeze = _as_2d(x)
-    xpad = jnp.concatenate(
-        [x2, jnp.zeros((obj.n_pad - obj.n, x2.shape[1]), dtype=x2.dtype)],
-        axis=0)
-    return xpad[obj.perm], squeeze
+    with jax.named_scope("repro.permute"):
+        x2, squeeze = _as_2d(x)
+        xpad = jnp.concatenate(
+            [x2, jnp.zeros((obj.n_pad - obj.n, x2.shape[1]),
+                           dtype=x2.dtype)], axis=0)
+        return xpad[obj.perm], squeeze
 
 
 def _from_permuted(obj, y_new: jnp.ndarray, squeeze: bool) -> jnp.ndarray:
-    y = y_new[obj.inv_perm[: obj.n]]
-    return y[:, 0] if squeeze else y
+    with jax.named_scope("repro.permute"):
+        y = y_new[obj.inv_perm[: obj.n]]
+        return y[:, 0] if squeeze else y
 
 
 def _fused_er_parts(x_new, er_p_vals, er_p_cols, er_p_rows, vec_size):
@@ -307,11 +314,14 @@ def _fused_er_parts(x_new, er_p_vals, er_p_cols, er_p_rows, vec_size):
     R = x_new.shape[1]
 
     def one_part(vals, cols, rows):
-        g = x_new[cols]                                  # (E, We, R)
-        ye = jnp.einsum("ew,ewr->er", vals, g)           # (E, R)
-        return jnp.zeros((vec_size, R), dtype=ye.dtype).at[rows].add(ye)
+        with jax.named_scope("repro.er.gather"):
+            g = x_new[cols]                              # (E, We, R)
+            ye = jnp.einsum("ew,ewr->er", vals, g)       # (E, R)
+        with jax.named_scope("repro.er.scatter"):
+            return jnp.zeros((vec_size, R), dtype=ye.dtype).at[rows].add(ye)
 
-    return jax.vmap(one_part)(er_p_vals, er_p_cols, er_p_rows)
+    with jax.named_scope("repro.er"):
+        return jax.vmap(one_part)(er_p_vals, er_p_cols, er_p_rows)
 
 
 @jax.jit
