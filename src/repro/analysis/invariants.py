@@ -50,6 +50,10 @@ Rule ids (stable — CI baselines and tests key on them):
                            GPU shared memory)
   halo-accounting          halo_words / buffer_words / per-device words
                            match the recorded schedule
+  index-bound.er-window    ER window lane-rows inside x, window-local
+                           columns < H·128
+  er-window-cover          every ER entry served once: by the ER window
+                           or by the leftover tables
 
 New formats plug in through the ``FormatSpec.invariants`` registry hook —
 ``verify`` consults it for any operator whose format name is registered, so
@@ -74,7 +78,7 @@ RULES = (
     "perm-bijection", "partition-capacity", "width-consistency",
     "staircase-monotone", "padding-sentinel", "fill-plan-bijection",
     "value-finite", "bucket-cover", "halo-coverage", "halo-push-race",
-    "halo-accounting",
+    "halo-accounting", "index-bound.er-window", "er-window-cover",
 )
 
 
@@ -363,6 +367,45 @@ def check_packed_host(pk) -> List[Finding]:
             out.append(_f("error", f"{site}.packed_vals", "padding-sentinel",
                           "nonzero values outside the recorded pack "
                           "scatter"))
+    w = getattr(e, "_er_window", None)
+    if w is not None:
+        out += check_er_window_host(w, e)
+    return out
+
+
+def _check_window_bounds(out, site, win_rows, cols, live, n_pad):
+    """Window lane-rows inside x, and the ``live`` entries' (a mask over
+    the tiles) window-local columns inside the window."""
+    h = np.shape(win_rows)[1]
+    _bound(out, site, "win_rows", win_rows, -(-n_pad // 128),
+           "index-bound.er-window")
+    _bound(out, site, "cols", np.asarray(cols)[live], h * 128,
+           "index-bound.er-window")
+
+
+def check_er_window_host(w, e) -> List[Finding]:
+    """Invariants of a host ``ERWindow`` against its base ``EHYB``."""
+    site = "ERWindow"
+    out: List[Finding] = []
+    _finite(out, site, "vals", w.vals)
+    src, dst = w.entry_slots()
+    if w.entries:
+        live = np.zeros(w.vals.size, dtype=bool)
+        live[dst] = True
+        _check_window_bounds(out, site, w.win_rows, w.cols,
+                             live.reshape(w.vals.shape), e.n_pad)
+    if w.left is not None:
+        _bound(out, site, "left.er_p_cols", w.left["er_p_cols"], e.n_pad,
+               "index-bound.er-global")
+    er = (np.asarray(e.fill_plan["er_dst"], np.int64)
+          if e.fill_plan is not None else np.flatnonzero(e.er_vals))
+    served = np.concatenate([src, w.left_entries()])
+    if (served.size != w.entries + w.leftover
+            or not np.array_equal(np.sort(served), np.sort(er))):
+        out.append(_f("error", f"{site}.plan", "er-window-cover",
+                      f"window and leftover serve {served.size} entries "
+                      f"({len(np.unique(served))} distinct) of {er.size} "
+                      f"live ER entries: each must be served once"))
     return out
 
 
@@ -469,6 +512,21 @@ def check_packed_device(d) -> List[Finding]:
         out.append(_f("error", f"{site}.col_starts", "width-consistency",
                       "packed stream overruns the packed value table"))
     _check_er_tables(out, site, d)
+    nz = 0
+    if d.win_vals is not None:
+        vals = np.asarray(d.win_vals)
+        _finite(out, site, "win_vals", vals)
+        _check_window_bounds(out, site, d.win_rows, d.win_cols, vals != 0,
+                             d.n_pad)
+        nz += int(np.count_nonzero(vals))
+    if d.er_p_vals is not None:
+        nz += int(np.count_nonzero(np.asarray(d.er_p_vals)))
+    er_nz = int(np.count_nonzero(np.asarray(d.er_vals)))
+    if nz != er_nz:
+        out.append(_f("error", f"{site}.win_vals", "er-window-cover",
+                      f"ER window and leftover hold {nz} nonzero values, "
+                      f"the ER table {er_nz}: each entry must be served "
+                      f"once"))
     _check_perm_pair(out, site, d.perm, d.inv_perm, d.n_pad)
     return out
 

@@ -44,9 +44,10 @@ from typing import Any, Callable, Dict, Optional
 
 import numpy as np
 
-from ..core.counters import span
+from ..core.counters import bump, span
 from ..core.ehyb import (EHYB, build_buckets, build_ehyb,
-                         group_er_by_partition, pack_staircase)
+                         group_er_by_partition, pack_er_window,
+                         pack_staircase)
 from ..core.matrices import SparseCSR
 from ..core.spmv import (COODevice, EHYBBucketsDevice, EHYBDevice,
                          EHYBPackedDevice, ELLDevice, HYBDevice, coo_spmv,
@@ -215,7 +216,10 @@ def _build_ehyb_packed(m, dtype, shared):
 
     with span("repro.bind.pack"):
         pk = shared_packed(m, shared)
-        group_er_by_partition(pk.base)   # memoized; the upload reads it
+        win = pack_er_window(pk.base)    # memoized; the upload reads it
+    bump("er_window.entries", win.entries)
+    bump("er_window.leftover", win.leftover)
+    bump("er_window.lane_rows", win.lane_rows)
     tuned = shared.get("tuned")
     with span("repro.bind.upload"):
         obj = EHYBPackedDevice.from_packed(
@@ -342,11 +346,19 @@ def _refill_ehyb_packed(obj, m, dtype, shared):
         pk = e._packed = (pk_old.refill(e)
                           if pk_old is not None and pk_old.pack_plan
                           is not None else pack_staircase(e))
-    g = group_er_by_partition(e)
+    win = getattr(e, "_er_window", None)
+    if win is None:
+        win_old = getattr(pk_old.base, "_er_window", None) \
+            if pk_old is not None else None
+        win = e._er_window = (win_old.refill(e.er_vals)
+                              if win_old is not None else pack_er_window(e))
     new = dataclasses.replace(
         obj, packed_vals=jnp.asarray(pk.packed_vals, dtype=dtype),
         er_vals=jnp.asarray(e.er_vals, dtype=dtype),
-        er_p_vals=jnp.asarray(g["er_p_vals"], dtype=dtype))
+        er_p_vals=(None if win.left is None
+                   else jnp.asarray(win.left["er_p_vals"], dtype=dtype)),
+        win_vals=(None if not win.entries
+                  else jnp.asarray(win.vals, dtype=dtype)))
     new.host_packed = pk
     return new
 

@@ -14,8 +14,9 @@ from .partition import (Partition, PartitionStrategy, available_strategies,
                         bfs_partition, choose_vec_size, get_strategy,
                         hub_partition, make_partition, mincut_partition,
                         natural_partition, register_strategy)
-from .ehyb import (EHYB, EHYBBuckets, PackedEHYB, build_buckets,
-                   build_ehyb, group_er_by_partition, pack_staircase)
+from .ehyb import (EHYB, EHYBBuckets, ERWindow, PackedEHYB, build_buckets,
+                   build_ehyb, group_er_by_partition, pack_er_window,
+                   pack_staircase)
 from .spmv import (COODevice, EHYBBucketsDevice, EHYBDevice,
                    EHYBPackedDevice, ELLDevice, HYBDevice, SpMVOperator,
                    build_spmv, coo_spmv, csr_spmv, dense_spmv,
@@ -34,6 +35,7 @@ __all__ = [
     "register_strategy",
     "EHYB", "EHYBBuckets", "PackedEHYB", "build_buckets", "build_ehyb",
     "group_er_by_partition", "pack_staircase", "EHYBPackedDevice",
+    "ERWindow", "pack_er_window",
     "COODevice", "EHYBBucketsDevice", "EHYBDevice", "ELLDevice", "HYBDevice",
     "SpMVOperator", "build_spmv", "coo_spmv",
     "csr_spmv", "dense_spmv", "ehyb_buckets_spmv",

@@ -203,8 +203,9 @@ class EHYB:
         tables are rewritten — one vectorized numpy scatter, no partitioning,
         no reordering, no sorting.  Memoized derived views that ``self``
         already carries (``group_er_by_partition`` tiles, width buckets, the
-        packed staircase) are refilled through their own recorded plans, so
-        downstream device builders touch no structure either.
+        packed staircase, the ER window) are refilled through their own
+        recorded plans, so downstream device builders touch no structure
+        either.
 
         ``new_data`` must be the CSR ``data`` stream of a matrix with the
         *identical* pattern (same ``indptr``/``indices``) — callers above
@@ -230,9 +231,7 @@ class EHYB:
                                       preprocess_seconds={})
             g = getattr(self, "_er_grouped", None)
             if g is not None:
-                gp = np.zeros_like(g["er_p_vals"])
-                gp[g["own"], g["slot"]] = er[g["src"]]
-                new._er_grouped = {**g, "er_p_vals": gp}
+                new._er_grouped = refill_grouped(g, er)
             def _refill_buckets(b):
                 return EHYBBuckets(
                     base=new, part_ids=b.part_ids,
@@ -253,6 +252,9 @@ class EHYB:
             pk = getattr(self, "_packed", None)
             if pk is not None:
                 new._packed = pk.refill(new)
+            w = getattr(self, "_er_window", None)
+            if w is not None:
+                new._er_window = w.refill(er)
         # structure passes cost exactly zero on a refill — that IS the point
         new.preprocess_seconds = {"partition": 0.0, "metadata": 0.0,
                                   "reorder": 0.0, "refill": refill.seconds,
@@ -406,7 +408,8 @@ def build_ehyb(m: SparseCSR, part: Optional[Partition] = None,
 # ER-by-partition grouping (per-partition ER tiles)
 # ---------------------------------------------------------------------------
 
-def group_er_by_partition(e: EHYB, sublane: int = 8) -> dict:
+def group_er_by_partition(e: EHYB, sublane: int = 8,
+                          keep: Optional[np.ndarray] = None) -> dict:
     """Map every ER slot to its owning partition (``er_row_idx // vec_size``).
 
     Every EHYB apply accumulates partition ``p``'s ER rows into the same
@@ -420,15 +423,22 @@ def group_er_by_partition(e: EHYB, sublane: int = 8) -> dict:
       ``er_p_cols``  (P, E, We) int32 global-new column indices
       ``er_p_rows``  (P, E)     int32 LOCAL row index within the partition
 
-    The result is memoized on ``e`` so the device builders (uniform + packed)
-    and the bytes model share one grouping pass.
+    ``keep`` (a boolean mask over the flat ``(er_rows, er_width)`` tables)
+    groups only those entries: the rows that hold one, every other entry of
+    them zeroed.  That is the ER window's leftover (:func:`pack_er_window`),
+    and it is not memoized.  Without it the result is memoized on ``e`` so
+    the device builders and the bytes model share one grouping pass.
     """
-    cached = getattr(e, "_er_grouped", None)
-    if cached is not None and cached["sublane"] == sublane:
-        return cached
-    bump("group_er")
+    if keep is None:
+        cached = getattr(e, "_er_grouped", None)
+        if cached is not None and cached["sublane"] == sublane:
+            return cached
+        bump("group_er")
     p_, v_, we = e.n_parts, e.vec_size, e.er_width
-    if e.fill_plan is not None:
+    if keep is not None:
+        keep = np.asarray(keep).reshape(-1, we)
+        live = np.flatnonzero(keep.any(axis=1))
+    elif e.fill_plan is not None:
         # pattern-derived live set: ER slots [0, n_er) hold the live rows by
         # construction (value-independent — explicit zeros stay live, so a
         # later ``refill`` can never change the grouping)
@@ -446,21 +456,290 @@ def group_er_by_partition(e: EHYB, sublane: int = 8) -> dict:
     own = np.empty(0, dtype=np.int64)
     slot = np.empty(0, dtype=np.int64)
     src = np.empty(0, dtype=np.int64)
+    kept = None
     if len(live):
         order = np.argsort(owner, kind="stable")
         src = live[order]
         own = owner[order]
         starts = np.concatenate([[0], np.cumsum(counts)])
         slot = np.arange(len(src)) - starts[own]
-        er_p_vals[own, slot] = e.er_vals[src]
-        er_p_cols[own, slot] = e.er_cols[src]
+        kept = None if keep is None else keep[src]
+        er_p_vals[own, slot] = _kept(e.er_vals[src], kept)
+        er_p_cols[own, slot] = _kept(e.er_cols[src], kept)
         er_p_rows[own, slot] = (e.er_row_idx[src] % v_).astype(np.int32)
     out = {"er_p_vals": er_p_vals, "er_p_cols": er_p_cols,
            "er_p_rows": er_p_rows, "has_er": bool(len(live)),
            "n_er_live": int(len(live)), "sublane": sublane,
-           # refill plan: er_p_vals[own, slot] = er_vals_new[src]
-           "own": own, "slot": slot, "src": src}
-    e._er_grouped = out
+           # refill plan: er_p_vals[own, slot] = er_vals_new[src], its
+           # entries outside ``keep`` zeroed (``kept``: (rows, We) or None)
+           "own": own, "slot": slot, "src": src, "kept": kept}
+    if keep is None:
+        e._er_grouped = out
+    return out
+
+
+def _kept(rows: np.ndarray, kept: Optional[np.ndarray]) -> np.ndarray:
+    return rows if kept is None else np.where(kept, rows, 0)
+
+
+def refill_grouped(g: dict, er_vals: np.ndarray) -> dict:
+    """``g`` (:func:`group_er_by_partition`) with values from a refilled
+    ``er_vals`` table, through its recorded plan."""
+    gp = np.zeros_like(g["er_p_vals"])
+    gp[g["own"], g["slot"]] = _kept(np.asarray(er_vals)[g["src"]],
+                                    g["kept"])
+    return {**g, "er_p_vals": gp}
+
+
+# ---------------------------------------------------------------------------
+# the ER window: each partition's ER served from a cached window of x
+# ---------------------------------------------------------------------------
+
+# Window-local ER columns are uint16 (slot·128 + lane, the paper's §3.4
+# compact index), which caps a partition's window at 2^16 / 128 lane-rows.
+ER_WINDOW_LANE_ROWS = (1 << 16) // LANES
+# VMEM bytes of one partition's window tiles (f32 values + uint16 columns):
+# the kernel holds a partition's tiles whole, double-buffered, so the ER
+# columns of a partition past this many bytes of tiles go to the leftover.
+ER_WINDOW_TILE_BYTES = 16 * 1024 * 1024
+# What one gather pass of the kernel over one (8, 128) window tile costs,
+# in elements of the XLA ER gather, on a TPU v5e at the benchmark cells'
+# size: about 3.9 ns a pass (H = 56 passes over each window tile) against
+# 6.9-7.6 ns an element of the padded (P, E, We) gather.
+ER_PASS_COST = 0.55
+
+
+@dataclasses.dataclass
+class ERWindow:
+    """The ER part of a partition, served from an explicitly cached window
+    of x (the Pallas kernel's ER stage).
+
+    A partition's *ER window* is a set of 128-lane rows of the flat,
+    128-padded permuted x that its ER entries read: ``win_rows[p]``, H
+    lane-rows (the most lane-rows any partition keeps, padded to a multiple
+    of 8; unused slots repeat lane-row 0).  The apply gathers those rows
+    once as whole rows, and the kernel gathers the entries from VMEM the way
+    it gathers from the partition's own x-slice.
+
+    Entries are stored in :class:`PackedEHYB`'s tile layout: column k of
+    partition p holds the entries of column k of the ER tables, rows in the
+    partition's own order, so the output lands in its (V, R) block with no
+    scatter.  Column k is the tiles that cover rows [0, ``col_rows[p, k]``)
+    (up to its last row with an entry in ER column k or past it), from
+    tile ``col_starts[p, k]``.  Columns are window-local:
+    ``slot·128 + (col & 127)``.
+
+    Each partition keeps its lane-rows that hold the most entries.  How
+    many is chosen from the pattern (:func:`pack_er_window`); the entries
+    on the other lane-rows (``leftover``) stay on the XLA ER path, in
+    :func:`group_er_by_partition`'s tables (``left``; None when nothing is
+    left over), and leave zeros in the window's tiles.  ``plan`` marks the
+    window's entries in the ER tables (``mask``) and ``left`` records its
+    own scatter, so :meth:`refill` touches no structure.
+    """
+
+    cap: int
+    lane_rows: int                    # H
+    win_rows: np.ndarray              # (P, H) int32 lane-rows of flat x
+    vals: np.ndarray                  # (P, T, Sb, 128) float
+    cols: np.ndarray                  # (P, T, Sb, 128) uint16 window-local
+    col_starts: np.ndarray            # (P, W+1) int32 first tile of col k
+    col_rows: np.ndarray              # (P, W) int32 rows covering col k
+    entries: int                      # ER entries served from the window
+    leftover: int                     # ER entries left to the XLA path
+    left: Optional[dict]              # group_er_by_partition(keep=...)
+    plan: dict                        # see _window_tiles
+
+    def _tiles(self, table: np.ndarray) -> np.ndarray:
+        return _window_tiles(table, self.plan, self.col_starts,
+                             self.vals.shape).reshape(self.vals.shape)
+
+    def refill(self, er_vals: np.ndarray) -> "ERWindow":
+        """The same window with values from a refilled ``er_vals`` table."""
+        er = np.asarray(er_vals)
+        left = (None if self.left is None
+                else refill_grouped(self.left, er))
+        return dataclasses.replace(self, vals=self._tiles(er), left=left)
+
+    def entry_slots(self) -> tuple:
+        """(src, dst): each window entry's flat index in the ER tables and
+        in the flat window tiles."""
+        mask = self.plan["mask"]
+        ids = self._tiles(np.arange(1, mask.size + 1).reshape(mask.shape))
+        dst = np.flatnonzero(ids)
+        return ids.reshape(-1)[dst] - 1, dst
+
+    def left_entries(self) -> np.ndarray:
+        """Flat ER-table indices of the leftover entries."""
+        if self.left is None:
+            return np.empty(0, dtype=np.int64)
+        g = self.left
+        we = g["er_p_vals"].shape[2]
+        return (g["src"][:, None] * we + np.arange(we))[g["kept"]]
+
+
+def _window_tiles(table: np.ndarray, plan: dict, col_starts: np.ndarray,
+                  shape: tuple) -> np.ndarray:
+    """The window's tiles (``shape`` = (P, T, Sb, 128)) of an (er_rows,
+    er_width) ER table: the entries ``plan["mask"]`` marks, each ER row
+    ``plan["slots"]`` moved to its row ``plan["rows"]`` of the padded x,
+    each partition's rows transposed into its columns' tiles; zeros
+    elsewhere."""
+    p_, n_tiles, sb, lanes = shape
+    tile = sb * lanes
+    width = col_starts.shape[1] - 1
+    v_ = plan["n_pad"] // p_
+    slots, rows = plan["slots"], plan["rows"]
+    n_sub = -(-v_ // tile)                       # tiles a column can take
+    by_col = np.zeros((p_, width, n_sub * tile), dtype=table.dtype)
+    by_col[rows // v_, :, rows % v_] = np.where(plan["mask"][slots, :width],
+                                                table[slots, :width], 0)
+    used = np.arange(n_sub) < np.diff(col_starts, axis=1)[:, :, None]
+    out = np.zeros((p_, n_tiles, tile), dtype=table.dtype)
+    out[np.arange(n_tiles) < col_starts[:, -1:]] = by_col.reshape(
+        p_, width * n_sub, tile)[used.reshape(p_, -1)]
+    return out
+
+
+def _column_rows(rows: np.ndarray, span: np.ndarray, n_pad: int, v_: int,
+                 width: int) -> np.ndarray:
+    """(P, width) ``col_rows``: for each partition and column k, 1 + its
+    last local row whose entries reach past column k; ER row ``rows[i]``
+    reaches ``span[i]`` columns (at most ``width``)."""
+    top = np.zeros((n_pad // v_, width + 1), dtype=np.int64)
+    np.maximum.at(top, (rows // v_, span), rows % v_ + 1)
+    return np.maximum.accumulate(top[:, ::-1], axis=1)[:, ::-1][:, 1:]
+
+
+def _window_lane_rows(e: EHYB, rows: np.ndarray, tiles: int,
+                      far: np.ndarray, cap: int) -> int:
+    """How many lane-rows each partition keeps in its window: the count c
+    (at most ``cap``) with the least modeled cost.  The kernel pays
+    ``ER_PASS_COST`` for each of H = c padded to 8 passes over each of the
+    window's ``tiles``; the XLA path pays one element for each slot of its
+    (P, E, We) tables, E the most rows of a partition with an entry past
+    its c-th lane-row.  ER row ``rows[i]``'s farthest entry sits on its
+    partition's ``far[i]``-th lane-row (most entries first)."""
+    p_, v_ = e.n_parts, e.vec_size
+    need = min(int(far.max()) + 1, cap)
+    # rows with an entry past lane-row c, for every c: per partition, a
+    # histogram of the rows' farthest lane-rows, summed from the top
+    hist = np.bincount((rows // v_) * (need + 1) + np.minimum(far, need),
+                       minlength=p_ * (need + 1)).reshape(p_, need + 1)
+    past = np.cumsum(hist[:, ::-1], axis=1)[:, ::-1]      # rank >= c
+    e_rows = past.max(axis=0)                             # per c in 0..need
+    cands = sorted({0, need, *range(8, need, 8)})
+    cost = [ER_PASS_COST * (-(-c // 8) * 8) * tiles
+            + p_ * (-(-int(e_rows[c]) // 8) * 8) * e.er_width
+            for c in cands]
+    return cands[int(np.argmin(cost))]
+
+
+def pack_er_window(e: EHYB, max_lane_rows: int = ER_WINDOW_LANE_ROWS
+                   ) -> ERWindow:
+    """Pack ``e``'s ER entries into per-partition ER windows (see
+    :class:`ERWindow`); ``max_lane_rows`` caps a window below the uint16
+    limit (for tests).  Memoized on ``e`` per cap.
+
+    How the ER splits between window and leftover follows from the
+    pattern: each partition ranks the lane-rows its ER reads by entries,
+    the window keeps the first c of them (``_window_lane_rows``: the cost
+    of H passes a window tile against the leftover's XLA gather), and the
+    columns of a partition past ``ER_WINDOW_TILE_BYTES`` of its tiles
+    join the leftover."""
+    cached = getattr(e, "_er_window", None)
+    if cached is not None and cached.cap == max_lane_rows:
+        return cached
+    bump("pack_er_window")
+    p_, v_, we = e.n_parts, e.vec_size, e.er_width
+    _, sb = lane_geometry(v_)
+    tile = sb * LANES
+    n128 = -(-e.n_pad // LANES)
+    # live entries of the (er_rows, er_width) tables, in their order: ER
+    # row by row, each row's entries in column order
+    if e.fill_plan is not None:
+        live = np.zeros(e.er_vals.size, dtype=bool)
+        live[e.fill_plan["er_dst"]] = True
+        live = live.reshape(e.er_vals.shape)
+    else:
+        live = e.er_vals != 0
+    slots = np.flatnonzero(live.any(axis=1))          # ER rows with an entry
+    cnt = live[slots].sum(axis=1)
+    span = we - np.argmax(live[slots, ::-1], axis=1)  # last entry's column + 1
+    row = e.er_row_idx[slots].astype(np.int64)
+    first = np.cumsum(cnt) - cnt                      # a row's first entry
+    kt = np.int32 if p_ * n128 < (1 << 31) else np.int64
+    part = np.repeat((row // v_).astype(kt), cnt)
+    col = e.er_cols[live]
+    n = col.size
+
+    # each partition's lane-rows, ranked by entries (most first).  Equal
+    # keys come in runs (a row's entries are in column order): index the
+    # runs' keys; every row starts a run
+    key = part * kt(n128) + (col >> 7).astype(kt)
+    brk = np.ones(n, dtype=bool)
+    np.not_equal(key[1:], key[:-1], out=brk[1:])
+    brk[first] = True
+    run = np.flatnonzero(brk)
+    run_len = np.diff(np.append(run, n))
+    keys, run_key = np.unique(key[run], return_inverse=True)
+    del key, brk, part
+    counts = np.bincount(run_key, weights=run_len, minlength=keys.size)
+    key_part = keys // n128
+    by_count = np.lexsort((keys, -counts, key_part))
+    ranked = key_part[by_count]
+    rank = np.empty(keys.size, dtype=np.int64)
+    rank[by_count] = np.arange(keys.size) - np.searchsorted(ranked, ranked)
+    # the window's columns (every ER column a partition's rows reach), cut
+    # where a partition's tiles would pass ER_WINDOW_TILE_BYTES
+    col_rows = _column_rows(row, span, e.n_pad, v_, we)
+    col_tiles = -(-col_rows // tile)
+    c = 0
+    if n:
+        far = np.maximum.reduceat(rank[run_key], np.searchsorted(run, first))
+        c = _window_lane_rows(e, row, int(col_tiles.sum()), far,
+                              max_lane_rows)
+    kept = np.flatnonzero(rank < c)                  # ascending keys
+    kept_part = key_part[kept]
+    slot_of = np.full(keys.size, -1, dtype=np.int32)
+    slot_of[kept] = (np.arange(kept.size)
+                     - np.searchsorted(kept_part, kept_part))
+    h = -(-c // 8) * 8
+    win_rows = np.zeros((p_, h), dtype=np.int32)
+    win_rows[kept_part, slot_of[kept]] = keys[kept] % n128
+    slot = np.repeat(slot_of[run_key], run_len)
+    del run, run_len, run_key
+
+    # the window's entries: on a kept lane-row, in a column that fits
+    max_tiles = max(ER_WINDOW_TILE_BYTES // (tile * 6), 1)
+    n_cols = (np.cumsum(col_tiles, axis=1) <= max_tiles).sum(axis=1)
+    mask = np.zeros(live.shape, dtype=bool)
+    mask[live] = slot >= 0
+    mask[slots] &= np.arange(we) < n_cols[row // v_][:, None]
+    n_win = int(np.count_nonzero(mask))
+    width = int(n_cols.max(initial=0)) if n_win else 0
+    col_rows = np.where(np.arange(width) < n_cols[:, None],
+                        col_rows[:, :width], 0)
+    col_starts = np.zeros((p_, width + 1), dtype=np.int32)
+    col_starts[:, 1:] = np.cumsum(-(-col_rows // tile), axis=1)
+    n_tiles = max(int(col_starts[:, -1].max(initial=0)), 1)
+    shape = (p_, n_tiles, sb, LANES)
+    plan = {"mask": mask, "slots": slots, "rows": row, "n_pad": e.n_pad}
+    col_tab = np.zeros(live.shape, dtype=np.uint16)
+    col_tab[live] = ((slot << 7) | (col & (LANES - 1))).astype(np.uint16)
+    vals = _window_tiles(e.er_vals, plan, col_starts, shape)
+    cols = _window_tiles(col_tab, plan, col_starts, shape)
+    del col_tab
+
+    # leftover entries: grouped per partition for the XLA ER path
+    n_left = n - n_win
+    left = (group_er_by_partition(e, keep=live & ~mask) if n_left
+            else None)
+    out = ERWindow(cap=max_lane_rows, lane_rows=h, win_rows=win_rows,
+                   vals=vals.reshape(shape), cols=cols.reshape(shape),
+                   col_starts=col_starts, col_rows=col_rows.astype(np.int32),
+                   entries=n_win, leftover=n_left, left=left, plan=plan)
+    e._er_window = out
     return out
 
 

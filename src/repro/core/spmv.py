@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .ehyb import EHYB, EHYBBuckets, group_er_by_partition
+from .ehyb import EHYB, EHYBBuckets, group_er_by_partition, pack_er_window
 from .matrices import SparseCSR
 
 
@@ -176,7 +176,13 @@ class EHYBDevice:
 @dataclasses.dataclass
 class EHYBPackedDevice:
     """Device-side packed-staircase EHYB: the Pallas kernel's tables
-    (see :class:`repro.core.ehyb.PackedEHYB` for the tile layout)."""
+    (see :class:`repro.core.ehyb.PackedEHYB` for the tile layout) and its
+    ER window (:class:`repro.core.ehyb.ERWindow`: ``win_rows`` and the
+    window's tiles, None on an ER-free matrix).  ``er_p_*`` hold only the
+    ER entries the window leaves over, in :class:`EHYBDevice`'s grouped
+    layout, and are None when there are none: the jitted apply drops either
+    stage statically.  The global ``er_*`` tables are kept for the
+    distributed path."""
 
     n: int
     n_pad: int
@@ -190,9 +196,14 @@ class EHYBPackedDevice:
     er_vals: jnp.ndarray
     er_cols: jnp.ndarray
     er_row_idx: jnp.ndarray
-    er_p_vals: jnp.ndarray      # (P, E, We) fused-ER tiles (see EHYBDevice)
+    er_p_vals: jnp.ndarray      # (P, E, We) leftover ER tiles, or None
     er_p_cols: jnp.ndarray
     er_p_rows: jnp.ndarray
+    win_rows: jnp.ndarray       # (P, H) int32 lane-rows of x, or None
+    win_vals: jnp.ndarray       # (P, Tw, Sb, 128) ER window value tiles
+    win_cols: jnp.ndarray       # (P, Tw, Sb, 128) uint16 window-local
+    win_starts: jnp.ndarray     # (P, We+1) int32 first tile of column k
+    win_col_rows: jnp.ndarray   # (P, We) int32 rows covering column k
     perm: jnp.ndarray
     inv_perm: jnp.ndarray
     # tuned kernel parameters (repro.tuning.TunedParams.token(): sorted
@@ -207,7 +218,9 @@ class EHYBPackedDevice:
         leaves = (self.packed_vals, self.packed_cols, self.col_starts,
                   self.col_rows, self.er_vals, self.er_cols, self.er_row_idx,
                   self.er_p_vals, self.er_p_cols, self.er_p_rows,
-                  self.perm, self.inv_perm)
+                  self.win_rows, self.win_vals, self.win_cols,
+                  self.win_starts, self.win_col_rows, self.perm,
+                  self.inv_perm)
         return leaves, (self.n, self.n_pad, self.n_parts, self.vec_size,
                         self.has_er, self.kparams)
 
@@ -217,18 +230,29 @@ class EHYBPackedDevice:
         return cls(*head, *leaves, kparams=kparams)
 
     @classmethod
-    def from_packed(cls, pk, dtype=jnp.float32, kparams: tuple = ()):
+    def from_packed(cls, pk, dtype=jnp.float32, kparams: tuple = (),
+                    window=None):
+        """Upload ``pk`` with its ER window (``pack_er_window(pk.base)``
+        unless ``window`` is given)."""
         e = pk.base
         t = e.as_jax(dtype=dtype)
-        g = group_er_by_partition(e)
-        return cls(e.n, e.n_pad, e.n_parts, e.vec_size, g["has_er"],
+        w = window if window is not None else pack_er_window(e)
+
+        def up(a, dt=None):
+            return None if a is None else jnp.asarray(a, dtype=dt)
+
+        win = ((w.win_rows, w.vals, w.cols, w.col_starts, w.col_rows)
+               if w.entries else (None,) * 5)
+        left = w.left or {}
+        return cls(e.n, e.n_pad, e.n_parts, e.vec_size,
+                   bool(w.entries or w.leftover),
                    jnp.asarray(pk.packed_vals, dtype=dtype),
                    jnp.asarray(pk.packed_cols),
                    jnp.asarray(pk.col_starts), jnp.asarray(pk.col_rows),
                    t["er_vals"], t["er_cols"], t["er_row_idx"],
-                   jnp.asarray(g["er_p_vals"], dtype=dtype),
-                   jnp.asarray(g["er_p_cols"]),
-                   jnp.asarray(g["er_p_rows"]),
+                   up(left.get("er_p_vals"), dtype),
+                   up(left.get("er_p_cols")), up(left.get("er_p_rows")),
+                   up(win[0]), up(win[1], dtype), *map(up, win[2:]),
                    t["perm"], t["inv_perm"], kparams=kparams)
 
 
@@ -309,8 +333,9 @@ def _from_permuted(obj, y_new: jnp.ndarray, squeeze: bool) -> jnp.ndarray:
 def _fused_er_parts(x_new, er_p_vals, er_p_cols, er_p_rows, vec_size):
     """Per-partition ER contribution in (P, V, R) layout: each partition
     gathers its own ER rows from the full x and scatters them LOCALLY into
-    its (V, R) output block.  No global scatter-add.  The XLA formats and
-    the Pallas format (whose kernel covers only the cached part) share it."""
+    its (V, R) output block.  No global scatter-add.  The XLA formats run
+    all their ER here; the Pallas format only what its ER windows leave
+    over."""
     R = x_new.shape[1]
 
     def one_part(vals, cols, rows):

@@ -4,12 +4,15 @@
 on CPU (exact, for validation), compiled through Mosaic on TPU.  Pass an
 explicit bool to override.
 
-One apply = one ``pallas_call`` for the cached sliced-ELL part plus the ER
-remainder in XLA (``core.spmv._fused_er_parts``), accumulated into the same
-per-partition (V, R) block.  The ``*_permuted`` variant consumes/produces
-permuted-space vectors so solver loops skip the per-call pad/``perm``/
-``inv_perm`` gathers entirely; an (n_pad, K) rhs runs the same kernel with
-the A tiles streamed once for all K columns.
+One apply = the ``ehyb_packed_spmv`` kernel over each partition's own
+x-slice and, in a second call, over its ER window (the lane-rows of x its ER
+entries read, gathered here as whole 128-lane rows), accumulated into the
+same per-partition (V, R) block.  ER entries a window leaves over stay in
+XLA (``core.spmv._fused_er_parts``); stencil and FEM matrices leave none,
+and that stage is then dropped statically.  The ``*_permuted``
+variant consumes/produces permuted-space vectors so solver loops skip the
+per-call pad/``perm``/``inv_perm`` gathers entirely; an (n_pad, K) rhs runs
+the same kernel with the A tiles streamed once for all K columns.
 """
 
 from __future__ import annotations
@@ -17,7 +20,9 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+import jax.numpy as jnp
 
+from ..core.partition import LANES
 from ..core.spmv import _as_2d, _from_permuted, _fused_er_parts, _to_permuted
 from . import ehyb_spmv as _k
 
@@ -48,7 +53,6 @@ def backend_supports_pallas(backend: str | None = None) -> bool:
     fallback chain without paying one doomed compile per plan.  On a TPU
     with no fault injection armed a failure is never an answer: the probe
     raises, since Pallas is the path the format promises there."""
-    import jax.numpy as jnp
     import numpy as np
 
     # function imports (the package attr `chaos` shadows the submodule)
@@ -93,6 +97,18 @@ def backend_supports_pallas(backend: str | None = None) -> bool:
     return ok
 
 
+def _er_window_x(x2: jax.Array, win_rows: jax.Array) -> jax.Array:
+    """(P, R, H, 128): each partition's ER window of x (n_pad, R), gathered
+    as whole 128-lane rows of the flat, 128-padded vector."""
+    with jax.named_scope("repro.er.window"):
+        n, r = x2.shape
+        rows = -(-n // LANES)
+        # rhs-major first, so the gathered rows keep 128 lanes minor
+        xr = jnp.pad(x2.T, ((0, 0), (0, rows * LANES - n)))
+        return jnp.transpose(xr.reshape(r, rows, LANES)[:, win_rows],
+                             (1, 0, 2, 3))
+
+
 @partial(jax.jit, static_argnames=("interpret",))
 def ehyb_spmv_packed_pallas_permuted(m, x_new: jax.Array, *,
                                      interpret: bool | None = None
@@ -110,11 +126,15 @@ def ehyb_spmv_packed_pallas_permuted(m, x_new: jax.Array, *,
     kp = dict(getattr(m, "kparams", ()) or ())
     x2, squeeze = _as_2d(x_new)
     r = x2.shape[1]
+    window = None
+    if m.win_vals is not None:
+        window = (_er_window_x(x2, m.win_rows), m.win_vals, m.win_cols,
+                  m.win_starts, m.win_col_rows)
     y_parts = _k.ehyb_packed_pallas(
         x2.reshape(m.n_parts, m.vec_size, r), m.packed_vals, m.packed_cols,
-        m.col_starts, m.col_rows, interpret=interpret,
+        m.col_starts, m.col_rows, er_window=window, interpret=interpret,
         gather_budget=kp.get("gather_budget"), rhs_chunk=kp.get("rhs_chunk"))
-    if m.has_er:
+    if m.er_p_vals is not None:                   # the window's leftover
         y_parts = y_parts + _fused_er_parts(
             x2, m.er_p_vals, m.er_p_cols, m.er_p_rows,
             m.vec_size).astype(y_parts.dtype)

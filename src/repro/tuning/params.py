@@ -58,8 +58,9 @@ SEARCH_SPACE: Dict[str, ParamSpec] = {
         "gather_budget", default=4 * 1024 * 1024,
         candidates=(1 << 20, 2 << 20, 4 << 20, 8 << 20),
         lo=64 * 1024, hi=64 * 1024 * 1024,
-        description="VMEM bytes of packed value/column tiles per grid "
-                    "step (sizes the partitions one Pallas step handles)"),
+        description="VMEM bytes of packed value/column tiles and x/y "
+                    "blocks per grid step (sizes the partitions one Pallas "
+                    "step handles)"),
     "rhs_chunk": ParamSpec(
         "rhs_chunk", default=16, candidates=(8, 16, 32),
         lo=1, hi=256,

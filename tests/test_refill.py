@@ -263,3 +263,40 @@ def test_integer_rhs_promotes_to_float_operator():
     assert jnp.issubdtype(y.dtype, jnp.floating)
     np.testing.assert_allclose(np.asarray(y, np.float64),
                                m.spmv(np.ones(m.n)), rtol=1e-5, atol=1e-5)
+
+
+def test_refill_of_an_er_window_operator_matches_a_fresh_bind():
+    """``update_values`` on an operator whose ER runs from ER windows
+    rewrites only the window's value tiles: no structure pass, no new jit
+    entry, and the same result as binding the new values from scratch."""
+    from repro import api
+    from repro.core import poisson3d27
+
+    m1 = poisson3d27(6)
+    m2 = _with_new_values(m1)
+    cfg = api.ExecutionConfig(format="ehyb_packed",
+                              partition_method="natural")
+    p = api.plan(m1, execution=cfg, cache=api.PlanCache())
+    op1 = p.bind(m1)
+    assert op1.obj.win_vals is not None and op1.obj.er_p_vals is None
+    x = jnp.asarray(np.random.default_rng(0).standard_normal(m1.n),
+                    jnp.float32)
+    jax.block_until_ready(op1 @ x)
+    probe = getattr(p._raw_apply(), "_cache_size", None)
+    if probe is None:
+        pytest.skip("jit cache-size probe unavailable on this jax")
+    n0 = probe()
+    before = counters.snapshot()
+    op2 = op1.update_values(m2)
+    y2 = np.asarray(op2 @ x, np.float64)
+    after = counters.snapshot()
+    assert _structure_work(before, after) == {}
+    assert after.get("pack_er_window", 0) == before.get("pack_er_window", 0)
+    assert probe() == n0
+    assert op2.obj.win_cols is op1.obj.win_cols
+    fresh = api.plan(m2, execution=cfg, cache=api.PlanCache()).bind(m2)
+    y_fresh = np.asarray(fresh @ x, np.float64)
+    np.testing.assert_allclose(y2, y_fresh, rtol=1e-6,
+                               atol=1e-6 * np.abs(y_fresh).max())
+    np.testing.assert_allclose(y2, m2.spmv(np.asarray(x, np.float64)),
+                               rtol=1e-4, atol=1e-4 * np.abs(y_fresh).max())

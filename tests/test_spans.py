@@ -294,5 +294,7 @@ def test_original_space_packed_apply_ops_carry_every_scope():
     hlo = jax.jit(lambda o, v: op.plan._raw_apply()(o, v)).lower(
         op.obj, jnp.ones(m.n)).compile().as_text()
     names = _op_names(hlo)
-    for scope in ("repro.er.gather", "repro.er.scatter", "repro.permute"):
+    for scope in ("repro.er.window", "repro.permute"):
         assert _has_scope(names, scope), scope
+    # every ER entry of the stencil sits in an ER window: no XLA ER gather
+    assert not _has_scope(names, "repro.er.gather")
