@@ -5,5 +5,6 @@
 is found by name: ``configs/<config>.json`` (the matrix), ``gen/<generator>.py``
 (builds it), ``traffic/<traffic>.json`` (the stream of right-hand sides),
 ``traffic/<kind>.py`` (drives it), ``workloads/<cell>.json`` (the checks'
-limits) and ``metrics/<metric>.py`` (one reader per per-layer metric).
+limits, the control and the CPU rehearsal's cut) and
+``metrics/<metric>.py`` (one reader per per-layer metric).
 """

@@ -8,16 +8,19 @@
 2. runs ``repro.api.plan`` -> ``Plan.bind`` with the configuration's
    format and value dtype and the traffic's workload context (the
    partition is the plan's own choice, warm-started from the tune store
-   under ``bench/.tune_store``);
-3. draws the pool of right-hand sides from ``--seed`` on the device and
-   warms the timed entry up on the first of them;
+   under ``bench/.tune_store``); a cell on more than one chip plans over
+   a one-axis mesh of its chips, in the ``"dist"`` context;
+3. draws the pool of right-hand sides from ``--seed`` on the device, puts
+   it whole on every chip of the mesh where there is one, and warms the
+   timed entry up on the first of them;
 4. drives the timed entry in a closed loop for ``seconds`` (one caller,
    each call ended by ``block_until_ready``; the call in flight at the
    deadline is finished and counted), optionally under the profiler;
 5. reads the device's peak memory, then checks a seed-drawn sample of the
    window's outputs against the float64 reference, with the guard
    counters, the plan's degradation record and, on a TPU, the Pallas
-   kernel in the compiled apply.
+   kernel in the compiled apply (for a sharded plan, the ``shard_map``
+   program its applies and solves run).
 
 It returns the result object that ``run.py`` prints as its last line.
 """
@@ -114,6 +117,30 @@ def make_pool(n: int, k: int, seed: int, count: int):
     return jax.block_until_ready(pool)
 
 
+def cell_mesh(chips: int):
+    """None on one chip; else a one-axis mesh (``"data"``) over the first
+    ``chips`` devices."""
+    if chips == 1:
+        return None
+    from repro.compat import make_mesh
+
+    return make_mesh((chips,), ("data",))
+
+
+def place(pool, mesh):
+    """The pool where a sharded operator's original-space entry takes its
+    vectors: whole on every chip of the mesh (the entry gathers them into
+    the permuted space with a permutation held on every chip), so that no
+    call of the window moves them.  Unchanged without a mesh."""
+    if mesh is None:
+        return pool
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    whole = NamedSharding(mesh, PartitionSpec())
+    return jax.block_until_ready([jax.device_put(v, whole) for v in pool])
+
+
 def _guard_counts() -> dict:
     from repro.core import counters
 
@@ -123,12 +150,17 @@ def _guard_counts() -> dict:
 
 def _kernel_missing(op, pool) -> int:
     """1 when the compiled permuted apply holds no Pallas kernel
-    (``tpu_custom_call``), else 0; only asked on a TPU."""
+    (``tpu_custom_call``), else 0; only asked on a TPU.  For a sharded
+    plan, the program asked is the ``shard_map``-ed original-space apply,
+    whose per-chip body (local tiles, halo exchange, ER) is the one the
+    sharded solve runs in its loop."""
     import jax
 
-    xp = op.to_space(pool[0])
-    hlo = jax.jit(lambda o, v: op.raw_apply_permuted(o, v)).lower(
-        op.obj, xp).compile().as_text()
+    if op.plan.is_sharded:
+        fn, x = op.raw_apply, pool[0]
+    else:
+        fn, x = op.raw_apply_permuted, op.to_space(pool[0])
+    hlo = jax.jit(lambda o, v: fn(o, v)).lower(op.obj, x).compile().as_text()
     return 0 if "tpu_custom_call" in hlo else 1
 
 
@@ -207,9 +239,11 @@ def run(spec: dict, seeds, seconds: float, trace: bool, *, t0: float,
     from bench import reference, trace_reduce
 
     cfg, traffic, cell = spec["config"], spec["traffic"], spec["cell"]
+    chips = int(spec["chips"])
     dtype = jnp.dtype(values or cfg["dtype"])
     k = int(traffic.get("k", 1))
     drv = driver(traffic)
+    mesh = cell_mesh(chips)
     cache_before = _cache_entries()
     guard_before = _guard_counts()
 
@@ -222,8 +256,12 @@ def run(spec: dict, seeds, seconds: float, trace: bool, *, t0: float,
         f"gen_s={time.perf_counter() - t0:.3f}")
     set_store(str(store))
     t = time.perf_counter()
-    p = api.plan(m, execution=api.ExecutionConfig(
-        format=cfg["format"], workload=traffic["workload"],
+    # "dist" is the only solver-loop context that a plan over a mesh of
+    # more than one device accepts (the traffic's own is refused there)
+    workload, on_mesh = ((traffic["workload"], {}) if mesh is None
+                         else ("dist", {"mesh": mesh}))
+    p = api.plan(m, **on_mesh, execution=api.ExecutionConfig(
+        format=cfg["format"], workload=workload,
         dtype=jnp.dtype(cfg["dtype"])))
     plan_s = time.perf_counter() - t
     t = time.perf_counter()
@@ -234,6 +272,7 @@ def run(spec: dict, seeds, seconds: float, trace: bool, *, t0: float,
 
     snap = counters.snapshot()
     log(f"plan: format={p.format} partition={p.partition_strategy} "
+        f"chips={chips} sharded={p.is_sharded} "
         f"plan_s={plan_s:.3f} bind_s={bind_s:.3f} tune_store hit="
         f"{snap.get('tune_store.hit', 0)} miss="
         f"{snap.get('tune_store.miss', 0)}")
@@ -243,7 +282,7 @@ def run(spec: dict, seeds, seconds: float, trace: bool, *, t0: float,
     setup_s = None
     a64 = None
     for seed in seeds:
-        pool = make_pool(m.n, k, seed, int(traffic["pool"]))
+        pool = place(make_pool(m.n, k, seed, int(traffic["pool"])), mesh)
         if setup_s is None:
             t = time.perf_counter()
             jax.block_until_ready(drv.call(op, pool[0], traffic)[0])
@@ -264,7 +303,7 @@ def run(spec: dict, seeds, seconds: float, trace: bool, *, t0: float,
         summary = None
         if trace:
             record = trace_reduce.extract(trace_reduce.find_xspace(tdir),
-                                          KERNELS)
+                                          KERNELS, chips=chips)
             shutil.rmtree(tdir, ignore_errors=True)
             summary = trace_reduce.reduce(record, KERNELS)
         iters, failed = drv.tally(infos, traffic)
@@ -301,7 +340,7 @@ def run(spec: dict, seeds, seconds: float, trace: bool, *, t0: float,
 
         if trace:
             rec = {"trace": summary, "plan_s": plan_s, "bind_s": bind_s,
-                   "ops": len(times), "iters": iters,
+                   "ops": len(times), "iters": iters, "chips": chips,
                    "device_kind": dev[0].device_kind, **size}
             metrics = {}
             for mt in spec["per_layer"]:
@@ -321,6 +360,7 @@ def run(spec: dict, seeds, seconds: float, trace: bool, *, t0: float,
         if trace:
             device["busy_s"] = summary["busy_s"]
             device["window_s"] = summary["window_s"]
+            device["busy_s_by_chip"] = summary["busy_s_by_chip"]
             result["breakdown"] = {"device_ops": summary["device_ops"],
                                    "idle_gaps": summary["idle_gaps"]}
         result["checks"] = {n: {"value": numbers[n], "limit": limits[n]}

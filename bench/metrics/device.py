@@ -40,11 +40,12 @@ def idle_pct(rec):
 
 
 def spmv_roofline_pct(rec):
-    """100 · (least time of one apply on this chip ÷ device busy time per
-    apply), the work being the matrix's (``bench.work``)."""
+    """100 · (least time of one apply on the cell's chips ÷ device busy
+    time per apply, a chip's mean), the work being the matrix's
+    (``bench.work``), shared evenly by the chips."""
     t = _trace(rec)
     if t is None or not rec.get("ops"):
         return None
     least = work.spmv_min_seconds(rec["n"], rec["nnz"], rec["k"],
                                   rec["dtype"], rec["device_kind"])
-    return 100.0 * least / (t["busy_s"] / rec["ops"])
+    return 100.0 * least / rec.get("chips", 1) / (t["busy_s"] / rec["ops"])

@@ -213,6 +213,38 @@ def test_small_run_is_correct(cell, trace, tmp_path):
         assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
+@pytest.mark.parametrize("cell", [w["name"] for w in BM["workloads"]])
+def test_every_cell_states_its_cut(cell):
+    """The rehearsal's cut is the cell's own data: keys of its
+    configuration, in ``bench/workloads/<cell>.json``, not in the code."""
+    spec = harness.load_cell(cell, BM)
+    small = spec["cell"]["small"]
+    assert small and set(small) <= set(spec["config"])
+    assert cell not in (ROOT / "bench" / "tests" / "cells.py").read_text()
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_hpcg_spec_on_four_devices_plans_over_the_mesh(trace, tmp_path):
+    """``hpcg.cg``'s spec with ``chips`` 4, on 4 virtual CPU devices at an
+    8 x 8 x 4 grid (n = 256: eight partitions, 64 rows a device, a halo
+    exchanged every apply): planned over the mesh in the ``"dist"``
+    context, and its solves correct.  A CPU trace holds no TPU plane, so
+    ``busy_s_by_chip`` is empty there (4 entries on four TPUs)."""
+    spec = harness.load_cell("hpcg.cg", BM)
+    spec["chips"] = 4
+    spec["config"]["grid"] = [8, 8, 4]
+    log = []
+    (r,) = run_small("hpcg.cg", tmp_path, spec=spec, trace=trace,
+                     log=log.append)
+    assert any("chips=4 sharded=True" in m for m in log), log
+    assert r["correct"], r["checks"]
+    assert r["checks"]["x_err"]["value"] < r["checks"]["x_err"]["limit"]
+    assert r["failed"] == 0 and r["attempted"] >= 1
+    assert r["device"]["count"] == 4
+    if trace:
+        assert r["device"]["busy_s_by_chip"] == []
+
+
 def _run_py(cwd, env_extra=None):
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     env.update({"JAX_PLATFORMS": "cpu"}, **(env_extra or {}))

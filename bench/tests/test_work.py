@@ -54,3 +54,17 @@ def test_every_peak_names_its_source():
     for kind, pk in table.items():
         assert pk["source"] and pk["hbm_bytes_per_s"] > 0, kind
         assert pk["bf16_flops_per_s"] > 0, kind
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_roofline_share_counts_the_matrix_once_over_the_cell_s_chips(chips):
+    """On several chips each does its share of the matrix's work in the
+    busy time of one chip (a mean), so the least time is divided by them."""
+    from bench.metrics import device
+
+    n, nnz = 943_296, 74_181_672
+    least = work.spmv_min_seconds(n, nnz, 1, "float32", "TPU v5 lite")
+    rec = {"trace": {"n_device_ops": 10, "busy_s": 4 * least / chips},
+           "ops": 2, "n": n, "nnz": nnz, "k": 1, "dtype": "float32",
+           "device_kind": "TPU v5 lite", "chips": chips}
+    assert device.spmv_roofline_pct(rec) == pytest.approx(50.0)

@@ -5,27 +5,31 @@ An addition to :mod:`bench.trace_reduce` that changes none of its numbers:
 
 * :func:`extract` reads a trace as ``trace_reduce.extract`` does, keeps the
   program's host spans (``repro.*``, opened by ``repro.core.counters.span``)
-  beside the harness's (``bench.*``), and gives each device op a fourth
-  field: its innermost ``repro.*`` scope (``jax.named_scope`` in the
-  program), or ``""`` where it has none.  The device's events carry only
-  the HLO instruction; its ``op_name`` comes from the optimized HLO the
-  profiler stores per program (:func:`program_op_names`), the program
+  beside the harness's (``bench.*``), and gives each device op its
+  innermost ``repro.*`` scope (``jax.named_scope`` in the program), or
+  ``""`` where it has none, as its fourth field, before its chip:
+  ``[name, start_ns, dur_ns, scope, chip]``.  The device's events carry
+  only the HLO instruction; its ``op_name`` comes from the optimized HLO
+  the profiler stores per program (:func:`program_op_names`), the program
   being the ``XLA Modules`` event that the op lies in.
 * :func:`reduce` returns ``trace_reduce.reduce``'s keys, computed on the
-  record without the fourth field, and two more: ``scope_s``, the device
-  self seconds of the ops in each scope, a scope also summing the scopes
-  named under it (``repro.er`` holds ``repro.er.gather`` and
-  ``repro.er.scatter``), and ``span_idle_s``, the device's idle seconds in
-  the traced window per innermost host span, every span.  Where a gap
-  crosses span edges, ``span_idle_s`` splits it there; ``idle_gaps`` puts
-  the whole gap to the span at its middle and keeps the ten largest.
+  record without the scope field, and two more: ``scope_s``, the device
+  self seconds of the ops in each scope as the mean over the chips, a
+  scope also summing the scopes named under it (``repro.er`` holds
+  ``repro.er.gather`` and ``repro.er.scatter``), and ``span_idle_s``, the
+  seconds of the traced window in which no chip runs an op, per innermost
+  host span, every span.  Where a gap crosses span edges, ``span_idle_s``
+  splits it there; ``idle_gaps`` puts the whole gap to the span at its
+  middle and keeps the ten largest.
 
-A record whose device ops have three fields reduces too: none has a scope.
+A record whose device ops have three fields reduces too: none has a scope;
+an op without its fifth field is chip 0's.
 
     python3 bench/trace_scopes.py <trace dir or .xplane.pb> [--out rec.json.gz]
 
-prints the reduction as one JSON object and, with ``--out``, writes the
-record of the window, times rebased to its start.
+prints the reduction of every TPU the trace holds as one JSON object and,
+with ``--out``, writes the record of the window, times rebased to its
+start.
 """
 
 from __future__ import annotations
@@ -144,72 +148,77 @@ def program_op_names(xspace_path) -> dict:
     return out
 
 
-def extract(xspace_path, kernels=()) -> dict:
+def extract(xspace_path, kernels=(), chips=1) -> dict:
     """``trace_reduce.extract``'s record of one trace, with the program's
     spans among the host spans and each device op's scope as its fourth
     field (see the module docstring)."""
-    record = tr.extract(xspace_path, kernels, span_prefix=SPANS)
-    scopes = op_scopes(xspace_path)
+    record = tr.extract(xspace_path, kernels, span_prefix=SPANS, chips=chips)
+    scopes = op_scopes(xspace_path, chips)
     assert len(scopes) == len(record["device"])
     for op, scope in zip(record["device"], scopes):
-        op.append(scope)
+        op.insert(3, scope)
     return record
 
 
-def op_scopes(xspace_path) -> list:
-    """The scope of each event on the first TPU's ``XLA Ops`` line, in the
-    line's order: the op's instruction looked up in the program whose
-    ``XLA Modules`` event it lies in."""
+def op_scopes(xspace_path, chips=1) -> list:
+    """The scope of each event on the ``XLA Ops`` line of each of the first
+    ``chips`` TPUs, in ``trace_reduce.extract``'s order: the op's
+    instruction looked up in the program whose ``XLA Modules`` event it
+    lies in on that chip."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(str(xspace_path))
-    tpus = sorted((p for p in pd.planes if p.name.startswith("/device:TPU:")),
-                  key=lambda p: p.name)
-    lines = {line.name: line for line in (tpus[0].lines if tpus else ())}
-    if "XLA Ops" not in lines:
-        return []
-    programs = program_op_names(xspace_path)
-    modules = sorted((ev.start_ns, ev.end_ns, ev.name) for ev in
-                     (lines["XLA Modules"].events if "XLA Modules" in lines
-                      else ()))
-    starts = [m[0] for m in modules]
+    planes = tr.tpu_planes(pd, chips)
+    programs = program_op_names(xspace_path) if planes else {}
     out = []
-    for ev in lines["XLA Ops"].events:
-        k = bisect.bisect_right(starts, ev.start_ns) - 1
-        names = (programs.get(modules[k][2], {})
-                 if k >= 0 and ev.start_ns < modules[k][1] else {})
-        instruction = ev.name.split(" = ", 1)[0].lstrip("%")
-        out.append(scope_of(names.get(instruction, "")))
+    for plane in planes:
+        lines = {line.name: line for line in plane.lines}
+        if "XLA Ops" not in lines:
+            continue
+        modules = sorted((ev.start_ns, ev.end_ns, ev.name) for ev in
+                         (lines["XLA Modules"].events
+                          if "XLA Modules" in lines else ()))
+        starts = [m[0] for m in modules]
+        for ev in lines["XLA Ops"].events:
+            k = bisect.bisect_right(starts, ev.start_ns) - 1
+            names = (programs.get(modules[k][2], {})
+                     if k >= 0 and ev.start_ns < modules[k][1] else {})
+            instruction = ev.name.split(" = ", 1)[0].lstrip("%")
+            out.append(scope_of(names.get(instruction, "")))
     return out
+
+
+def _chip(op) -> int:
+    return op[4] if len(op) > 4 else 0
 
 
 def _base(record, kernels=()):
     """``trace_reduce.reduce`` of the record without the scope field, and
-    the traced window ``(lo, hi)`` it took: from the ``bench.window`` span,
-    else from the first device op, for ``window_s``."""
-    dev, host = record.get("device", []), record.get("host", [])
-    out = tr.reduce({"device": [e[:3] for e in dev], "host": host}, kernels)
-    win = [s for n, s, _ in host if n == tr.WINDOW_SPAN]
-    lo = win[0] if win else min((e[1] for e in dev), default=0.0)
-    return out, lo, lo + out["window_s"] * 1e9
+    the traced window ``(lo, hi)`` it took."""
+    three = {**record, "device": [[*e[:3], _chip(e)]
+                                  for e in record.get("device", [])]}
+    lo, hi = tr.window(three)
+    return tr.reduce(three, kernels), lo, hi
 
 
 def reduce(record: dict, kernels=()) -> dict:
     """``trace_reduce.reduce``'s summary plus ``scope_s`` and
     ``span_idle_s`` (see the module docstring)."""
-    dev = record.get("device", [])
-    host = record.get("host", [])
+    chips = record.get("chips", 1)
+    spans = [(n, s, s + d) for n, s, d in record.get("host", [])]
     out, lo, hi = _base(record, kernels)
-    spans = [(n, s, s + d) for n, s, d in host]
-    ops = [(e[3] if len(e) > 3 else "", max(e[1], lo), min(e[1] + e[2], hi))
-           for e in dev]
-    ops = [op for op in ops if op[2] > op[1]]
+    per_chip = [[] for _ in range(chips)]
+    for e in record.get("device", []):
+        a, b = max(e[1], lo), min(e[1] + e[2], hi)
+        if b > a:
+            per_chip[_chip(e)].append((e[3] if len(e) > 3 else "", a, b))
     scope_ns = collections.Counter()
-    for scope, t in tr._self_times(ops):
-        parts = scope.split(".")
-        for i in range(2, len(parts) + 1):
-            scope_ns[".".join(parts[:i])] += t
-    busy = tr._union([(s, e) for _, s, e in ops])
+    for ops in per_chip:
+        for scope, t in tr._self_times(ops):
+            parts = scope.split(".")
+            for i in range(2, len(parts) + 1):
+                scope_ns[".".join(parts[:i])] += t
+    busy = tr._union([(s, e) for ops in per_chip for _, s, e in ops])
     inner = sorted(((n, s, e) for n, s, e in spans if n != tr.WINDOW_SPAN),
                    key=lambda t: t[2] - t[1])
     edges = [lo] + [x for iv in busy for x in iv] + [hi]
@@ -222,22 +231,26 @@ def reduce(record: dict, kernels=()) -> dict:
             cover = next((n for n, s, e in inner if s <= mid <= e),
                          tr.UNCOVERED)
             idle_ns[cover] += b - a
-    out["scope_s"] = {k: v * 1e-9 for k, v in sorted(scope_ns.items())}
+    per = 1e-9 / max(chips, 1)
+    out["scope_s"] = {k: v * per for k, v in sorted(scope_ns.items())}
     out["span_idle_s"] = {k: v * 1e-9 for k, v in idle_ns.most_common()}
     return out
 
 
 def window_record(record: dict) -> dict:
     """The events of ``record`` that overlap its traced window, times in
-    whole nanoseconds from the window's start."""
+    whole nanoseconds from the window's start, and its ``chips``."""
     _, lo, hi = _base(record)
 
     def keep(events):
         return [[e[0], round(e[1] - lo), round(e[2]), *e[3:]]
                 for e in events if e[1] < hi and e[1] + e[2] > lo]
 
-    return {"device": keep(record.get("device", [])),
-            "host": keep(record.get("host", []))}
+    out = {"device": keep(record.get("device", [])),
+           "host": keep(record.get("host", []))}
+    if "chips" in record:
+        out["chips"] = record["chips"]
+    return out
 
 
 def main(argv=None) -> int:
@@ -256,7 +269,7 @@ def main(argv=None) -> int:
     path = args.trace
     if not path.endswith(".xplane.pb"):
         path = tr.find_xspace(path)
-    record = extract(path, KERNELS)
+    record = extract(path, KERNELS, chips=None)
     if args.out:
         with gzip.open(args.out, "wt") as f:
             json.dump(window_record(record), f, separators=(",", ":"))
